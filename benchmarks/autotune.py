@@ -182,7 +182,7 @@ def main() -> None:
     ap.add_argument("--iters", type=int, default=3)
     ap.add_argument("--out", default=None,
                     help="cache file (default: $REPRO_AUTOTUNE_CACHE or "
-                         "~/.cache/repro_bcpnn/autotune.json)")
+                         "autotune.json at the checkout root)")
     args = ap.parse_args()
     if args.smoke:
         entries, path = autotune(["smoke"], SMOKE_CANDIDATES, iters=1,

@@ -8,9 +8,9 @@ Prints ``name,value,unit`` CSV rows:
                             (writes BENCH_kernels.json)
   * bench_roofline_bcpnn  — Fig. 6 roofline placement (TPU target)
   * bench_lm_rooflines    — assigned-arch dry-run roofline table
-  * bench_train_dp        — Trainer DP fit images/s at 1/2/4-way CPU
-                            meshes + elastic kill-resume overhead
-                            (writes BENCH_train_dp.json; subprocesses)
+  * bench_train_dp        — Trainer DP fit images/s at 1/2/4-way meshes
+                            of the devices present + elastic kill-resume
+                            overhead (writes BENCH_train_dp.json)
 
 ``--assert-patchy-speedup`` is the CI smoke gate for the compact patchy
 schedule: it reruns the kernels bench and fails if the measured

@@ -76,23 +76,19 @@ def check_recompile_sentinel() -> List[str]:
                       online_learning=True, feedback_batch=4,
                       adaptive_buckets=True)
     slot = svc._slot(None)
-    cache_size = getattr(slot.infer_fn, "_cache_size", None)
-    if cache_size is None:
-        return ["jit tracing-cache introspection (_cache_size) is "
-                "unavailable in this jax version — sentinel cannot run"]
 
     problems: List[str] = []
     svc.start(warmup=True)
     try:
-        n_infer0 = slot.infer_fn._cache_size()
-        n_learn0 = slot.learn_fn._cache_size()
+        n_infer0 = len(slot.infer_exe)
+        n_learn0 = int(slot.learn_exe is not None)
         if n_infer0 != len(buckets):
             problems.append(
-                f"warmup compiled {n_infer0} infer entries for "
+                f"warmup compiled {n_infer0} infer programs for "
                 f"{len(buckets)} buckets — bucket set and compile set "
                 f"disagree")
         if n_learn0 != 1:
-            problems.append(f"warmup compiled {n_learn0} learn entries, "
+            problems.append(f"warmup compiled {n_learn0} learn programs, "
                             f"expected exactly 1 (the feedback_batch shape)")
         rng = np.random.default_rng(0)
         ni = spec.input_geom.N
@@ -105,17 +101,16 @@ def check_recompile_sentinel() -> List[str]:
             svc.feedback(rng.random(ni).astype(np.float32), i % 2)
     finally:
         svc.stop()
-    n_infer1 = slot.infer_fn._cache_size()
-    n_learn1 = slot.learn_fn._cache_size()
+    n_infer1 = len(slot.infer_exe)
+    n_learn1 = int(slot.learn_exe is not None)
     if n_infer1 != n_infer0:
         problems.append(
-            f"infer jit recompiled during serving: {n_infer0} -> "
-            f"{n_infer1} cache entries — a request escaped its shape "
-            f"bucket or the spec's jit key churned")
+            f"infer program compiled during serving: {n_infer0} -> "
+            f"{n_infer1} programs — a request escaped its shape bucket")
     if n_learn1 != n_learn0:
         problems.append(
-            f"learn jit recompiled during serving: {n_learn0} -> "
-            f"{n_learn1} cache entries — a feedback fold escaped the "
+            f"learn program compiled during serving: {n_learn0} -> "
+            f"{n_learn1} programs — a feedback fold escaped the "
             f"fixed feedback_batch shape")
     return problems
 

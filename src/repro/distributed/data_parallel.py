@@ -37,7 +37,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..core.bcpnn_layer import (
@@ -262,9 +261,9 @@ def make_data_parallel_unsupervised_step(spec: NetworkSpec, mesh: Mesh,
                               n_shards)
         return _train_projection_body(state, spec, layer, h, axis, n_shards)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         step, mesh=mesh, in_specs=(P(), P(axis)), out_specs=P(),
-        check_rep=False))
+        check_vma=False))
 
 
 def make_data_parallel_supervised_step(spec: NetworkSpec, mesh: Mesh,
@@ -282,9 +281,9 @@ def make_data_parallel_supervised_step(spec: NetworkSpec, mesh: Mesh,
         labels = jax.lax.all_gather(labels_l, axis, tiled=True)
         return _supervised_body(state, spec, xf, labels, axis, n_shards)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         step, mesh=mesh, in_specs=(P(), P(axis), P(axis)), out_specs=P(),
-        check_rep=False))
+        check_vma=False))
 
 
 # ------------------------------------------- scan-over-batches epochs ----
@@ -332,9 +331,9 @@ def make_data_parallel_projection_epoch(spec: NetworkSpec, mesh: Mesh,
 
         in_specs = (P(), P(None, axis))
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         epoch, mesh=mesh, in_specs=in_specs, out_specs=P(),
-        check_rep=False))
+        check_vma=False))
 
 
 def make_data_parallel_supervised_epoch(spec: NetworkSpec, mesh: Mesh,
@@ -374,6 +373,6 @@ def make_data_parallel_supervised_epoch(spec: NetworkSpec, mesh: Mesh,
 
         in_specs = (P(), P(None, axis), P(None, axis))
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         epoch, mesh=mesh, in_specs=in_specs, out_specs=P(),
-        check_rep=False))
+        check_vma=False))

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .hc_softmax import softmax_tile
 from .padding import pad_axis, pad_hc_axis, unpad_hc_axis
 from .tiling import NEG, SUBLANE, lane_multiple, pad_hc_spec, pad_spec
 
@@ -41,12 +42,7 @@ def _kernel(x_ref, w_ref, b_ref, o_ref, acc_ref, *, k_steps: int, n_mc: int, gai
     @pl.when(k == k_steps - 1)
     def _epilogue():
         s = (acc_ref[...] + b_ref[...]) * gain       # (tb, tj)
-        tb, tj = s.shape
-        s = s.reshape(tb, tj // n_mc, n_mc)
-        s = s - jnp.max(s, axis=-1, keepdims=True)
-        e = jnp.exp(s)
-        out = e / jnp.sum(e, axis=-1, keepdims=True)
-        o_ref[...] = out.reshape(tb, tj).astype(o_ref.dtype)
+        o_ref[...] = softmax_tile(s, n_mc).astype(o_ref.dtype)
 
 
 @functools.partial(

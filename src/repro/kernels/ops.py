@@ -24,7 +24,6 @@ Two per-projection execution choices happen here (DESIGN.md §7):
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -41,10 +40,6 @@ from .hc_softmax import hc_softmax_pallas
 from .patchy import compact_forward, compact_update, patchy_forward, patchy_update
 from .quant import quant_compact_forward, quant_fwd_pallas, quant_patchy_forward
 
-# Force interpret mode on ("1") or off ("0") regardless of the detected
-# backend — tests and CI pin the interpreter explicitly with this.
-ENV_INTERPRET = "REPRO_PALLAS_INTERPRET"
-
 
 @functools.lru_cache(maxsize=1)
 def _default_backend() -> str:
@@ -54,9 +49,8 @@ def _default_backend() -> str:
 
 
 def _interpret() -> bool:
-    env = os.environ.get(ENV_INTERPRET)
-    if env is not None:
-        return env.strip().lower() not in ("0", "false", "")
+    """Kernels compile to Mosaic on a TPU and run in the Pallas
+    interpreter everywhere else; nothing overrides the platform."""
     return _default_backend() != "tpu"
 
 

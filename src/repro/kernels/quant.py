@@ -48,6 +48,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..core.compact import unit_indices
+from .hc_softmax import softmax_tile
 from .padding import pad_axis, pad_hc_axis, unpad_hc_axis
 from .tiling import LANE, NEG, pad_hc_spec, pad_mc, pad_spec
 
@@ -159,12 +160,7 @@ def _quant_kernel(x_ref, w_ref, b_ref, s_ref, o_ref, acc_ref, *,
         # Scale-folded dequant straight into the fp32 logit stage: one
         # fused multiply-add per unit, then the standard per-HC softmax.
         s = (acc_ref[...].astype(jnp.float32) * s_ref[...] + b_ref[...]) * gain
-        tb, tj = s.shape
-        s = s.reshape(tb, tj // n_mc, n_mc)
-        s = s - jnp.max(s, axis=-1, keepdims=True)
-        e = jnp.exp(s)
-        out = e / jnp.sum(e, axis=-1, keepdims=True)
-        o_ref[...] = out.reshape(tb, tj).astype(o_ref.dtype)
+        o_ref[...] = softmax_tile(s, n_mc).astype(o_ref.dtype)
 
 
 @functools.partial(

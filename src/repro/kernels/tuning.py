@@ -13,15 +13,19 @@ Cache format (DESIGN.md §7):
 
 where the dims are the wrapper's shape-defining integers in sorted-key
 order.  Location: ``$REPRO_AUTOTUNE_CACHE`` if set, else
-``~/.cache/repro_bcpnn/autotune.json``.  Lookups are memoized per file
-mtime, so a fresh autotune run is picked up without restarting, and a
-missing/corrupt cache degrades to the defaults silently.
+``autotune.json`` at the root of the checkout -- a tuned cache is part
+of the program it tunes, so it lives with the code and git keeps it;
+nothing outside the checkout changes block sizes.  Lookups are memoized
+per file mtime, so a fresh autotune run is picked up without
+restarting, and a missing/corrupt cache degrades to the defaults
+silently.
 """
 from __future__ import annotations
 
 import functools
 import json
 import os
+import pathlib
 from typing import Dict, Optional
 
 import jax
@@ -32,9 +36,13 @@ VERSION = 1
 _BLOCK_KEYS = ("block_b", "block_h", "block_i", "block_j", "block_k")
 
 
+# src/repro/kernels/tuning.py -> the checkout root, three levels up
+DEFAULT_CACHE = str(
+    pathlib.Path(__file__).resolve().parents[3] / "autotune.json")
+
+
 def cache_path() -> str:
-    return os.environ.get(ENV_CACHE) or os.path.join(
-        os.path.expanduser("~"), ".cache", "repro_bcpnn", "autotune.json")
+    return os.environ.get(ENV_CACHE) or DEFAULT_CACHE
 
 
 def entry_key(kernel: str, backend: Optional[str] = None, **dims: int) -> str:

@@ -53,6 +53,7 @@ from ..serve import (
     BCPNNRouter, BCPNNService, Overloaded, ServeError, StreamSpec,
     run_multi_open_loop, run_open_loop,
 )
+from .compile_cache import enable_compile_cache
 
 
 def _report(tag: str, snap: dict, extra: str = "") -> None:
@@ -192,6 +193,7 @@ def main():
                          "manifest's own infer_dtype tag")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.ckpt:
         serve_checkpoints(args)

@@ -19,9 +19,11 @@ Phases:
      (column-sharded DP is exact for any shard count), and the recovery
      overhead is reported.
 
-``--devices N`` forces an N-way CPU mesh (via
-``--xla_force_host_platform_device_count``, so it must act before jax
-initializes — this module therefore imports jax inside ``main``).
+``--devices N`` is the data-axis width, taken from the devices JAX
+finds (the mesh shrinks to fit when fewer are present).  With no
+accelerator attached, ``main`` gives the host (CPU) platform N virtual
+devices via ``--xla_force_host_platform_device_count``; the flag must act
+before jax initializes, so this module imports jax inside ``run``.
 ``--json PATH`` writes the measured numbers for benchmarks/run.py.
 """
 from __future__ import annotations
@@ -38,7 +40,7 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--smoke", action="store_true",
                    help="assert bit-exactness + recovery, tiny workload")
     p.add_argument("--devices", type=int, default=2,
-                   help="CPU device count to force (data-axis width)")
+                   help="data-axis width (virtual devices on the CPU)")
     p.add_argument("--side", type=int, default=12)
     p.add_argument("--depth", type=int, default=2)
     p.add_argument("--classes", type=int, default=5)
@@ -64,13 +66,21 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
+    # The flag sizes the host (CPU) platform only: an attached TPU keeps
+    # its own devices and stays JAX's default.
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (
             flags + f" --xla_force_host_platform_device_count="
             f"{args.devices}").strip()
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
+    return run(args)
 
+
+def run(args: argparse.Namespace) -> int:
+    """The three phases on the devices this process already has (what
+    benchmarks/bench_train_dp.py calls in-process)."""
     import jax
     import numpy as np
 
@@ -94,7 +104,7 @@ def main(argv=None) -> int:
                             **kw)
         return time.perf_counter() - t0, stats
 
-    out = {"devices": args.devices, "train_n": len(xtr),
+    out = {"train_n": len(xtr),
            "batch": args.batch, "epochs": args.epochs,
            "depth": spec.depth}
 
@@ -117,6 +127,9 @@ def main(argv=None) -> int:
 
     # ---- phase 2: data-parallel fit on the full mesh -------------------
     mesh = elastic_mesh((args.devices,), ("data",))
+    n_dev = mesh.devices.size
+    out["devices"] = n_dev
+    out["platform"] = mesh.devices.flat[0].platform
     print(f"[train-dp] mesh: {describe_failure_domains(mesh)}")
     tr2 = Trainer(spec, seed=0, mesh=mesh)
     if args.warmup:
@@ -129,7 +142,7 @@ def main(argv=None) -> int:
     out["dp_acc"] = float(acc2)
     if t_single is not None:
         out["scaling_x"] = t_single / t_dp
-    print(f"[train-dp] {args.devices}-way DP: {t_dp:.2f}s "
+    print(f"[train-dp] {n_dev}-way DP: {t_dp:.2f}s "
           f"({n_img / t_dp:.0f} img/s), acc {acc2:.3f}"
           + (f", scaling {t_single / t_dp:.2f}x" if t_single else ""))
     if ref is not None:
@@ -172,8 +185,9 @@ def main(argv=None) -> int:
             print(f"[train-dp] {e} after {t_killed:.2f}s")
         # Recovery ladder: largest mesh from the survivors, restore the
         # latest checkpoint, resume from its cursor.
-        survivors = jax.devices()[:-1] if args.devices > 1 else jax.devices()
-        mesh_r = elastic_mesh((args.devices,), ("data",), devices=survivors)
+        mesh_devices = list(mesh.devices.flat)
+        survivors = mesh_devices[:-1] if n_dev > 1 else mesh_devices
+        mesh_r = elastic_mesh((n_dev,), ("data",), devices=survivors)
         print(f"[train-dp] rebuilt mesh from {len(survivors)} survivors: "
               f"{describe_failure_domains(mesh_r)}")
         t_rec0 = time.perf_counter()

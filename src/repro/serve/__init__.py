@@ -9,8 +9,8 @@ reroute-on-overload, engine-loss recovery, replica reconciliation
 from .batching import MicroBatcher, Request, default_buckets, pad_group, pick_bucket
 from .engine import BCPNNService, ServeResult, cycle_batch
 from .errors import (
-    DeadlineExceeded, EngineKilled, FaultInjected, NoHealthyReplica,
-    Overloaded, Quarantined, ServeError, WorkerDied,
+    CompileFailed, DeadlineExceeded, EngineKilled, FaultInjected,
+    NoHealthyReplica, Overloaded, Quarantined, ServeError, WorkerDied,
 )
 from .faultinject import POINTS, Fault, FaultInjector
 from .handle import EngineHandle, LocalEngineHandle
@@ -27,6 +27,7 @@ __all__ = [
     "BCPNNService", "ServeResult", "cycle_batch",
     "ServeError", "Overloaded", "DeadlineExceeded", "WorkerDied",
     "Quarantined", "FaultInjected", "NoHealthyReplica", "EngineKilled",
+    "CompileFailed",
     "POINTS", "Fault", "FaultInjector",
     "EngineHandle", "LocalEngineHandle", "BCPNNRouter",
     "chunk_bounds", "merge_replica_states", "states_bitwise_equal",

@@ -78,7 +78,8 @@ from ..core.network import (
 from ..distributed.fault import StepTimer
 from .batching import MicroBatcher, Request, default_buckets, pad_group, pick_bucket
 from .errors import (
-    DeadlineExceeded, EngineKilled, Overloaded, Quarantined, WorkerDied,
+    CompileFailed, DeadlineExceeded, EngineKilled, Overloaded, Quarantined,
+    WorkerDied,
 )
 from .faultinject import FaultInjector
 from .metrics import ServeMetrics
@@ -153,6 +154,43 @@ class _ModelSlot:
     # until revalidate() re-arms it.
     last_good: Any = None
     quarantined: bool = False
+    # Programs compiled ahead of their first call: one per bucket for
+    # inference, one for the feedback fold.  The worker runs only these,
+    # so a compile failure surfaces as ``CompileFailed``, never as a
+    # request's own error.
+    infer_exe: Dict[int, Any] = dataclasses.field(default_factory=dict)
+    learn_exe: Any = None
+
+    def _compile(self, what: str, fn: Any, *args: Any) -> Any:
+        try:
+            return fn.lower(*args).compile()
+        except Exception as e:
+            raise CompileFailed(f"model {self.name!r}: {what} failed to "
+                                f"compile: {type(e).__name__}: {e}") from e
+
+    def infer_program(self, bucket: int) -> Any:
+        """The compiled serving forward for one bucket (compiled on
+        first use)."""
+        exe = self.infer_exe.get(bucket)
+        if exe is None:
+            ni = self.spec.input_geom.N
+            exe = self._compile(
+                f"infer (bucket {bucket})", self.infer_fn, self.pack,
+                jax.ShapeDtypeStruct((bucket, ni), jnp.float32),
+                jax.ShapeDtypeStruct((bucket,), jnp.float32))
+            self.infer_exe[bucket] = exe
+        return exe
+
+    def learn_program(self, batch: int) -> Any:
+        """The compiled feedback fold over ``batch`` labeled samples
+        (compiled on first use; one fold shape per engine)."""
+        if self.learn_exe is None:
+            ni = self.spec.input_geom.N
+            self.learn_exe = self._compile(
+                "feedback fold", self.learn_fn, self.state,
+                jax.ShapeDtypeStruct((batch, ni), jnp.float32),
+                jax.ShapeDtypeStruct((batch,), jnp.int32))
+        return self.learn_exe
 
     def repack(self) -> None:
         """Re-derive the serving-dtype inference weights from the fp32
@@ -508,23 +546,17 @@ class BCPNNService:
 
     def warmup(self) -> None:
         """Pre-compile every (model, bucket) shape (and the learn shapes)
-        so no request pays a compile on the serving path."""
+        so no request pays a compile on the serving path; a program that
+        cannot compile raises ``CompileFailed`` here, on the caller's
+        thread."""
         for slot in self._slots.values():
             self._warm_slot(slot)
 
     def _warm_slot(self, slot: _ModelSlot) -> None:
-        ni = slot.spec.input_geom.N
         for b in self._buckets:
-            probs, _ = slot.infer_fn(slot.pack,
-                                     jnp.zeros((b, ni), jnp.float32),
-                                     jnp.zeros((b,), jnp.float32))
-            jax.block_until_ready(probs)
+            slot.infer_program(b)
         if self.online_learning:
-            st = slot.learn_fn(
-                slot.state,
-                jnp.zeros((self.feedback_batch, ni), jnp.float32),
-                jnp.zeros((self.feedback_batch,), jnp.int32))
-            jax.block_until_ready(st.readout.w)  # discard: compile only
+            slot.learn_program(self.feedback_batch)
 
     # ---------------------------------------------------------- front-end --
     def submit(self, x: np.ndarray, model: Optional[str] = None,
@@ -804,6 +836,8 @@ class BCPNNService:
                         # flush EVERY model's buffer, one batch at a time
                         self._fold_feedback(force=True)
                     return
+            except CompileFailed:
+                raise  # fatal: _run kills the engine, nothing hangs
             except Exception as e:
                 # Supervised: scheduler/adapt/metrics bugs are counted
                 # and survived (the request-completing paths below have
@@ -973,6 +1007,8 @@ class BCPNNService:
             return
         try:
             self._infer_group(slot, group)
+        except CompileFailed:
+            raise
         except Exception as e:
             slot.metrics.record_crash()
             if len(group) == 1:
@@ -1006,8 +1042,8 @@ class BCPNNService:
                 inj.check_group([r.id for r in group])
                 inj.raise_if("infer-raise")
             x, valid = pad_group([r.x for r in group], bucket)
-            probs, pred = slot.infer_fn(slot.pack, jnp.asarray(x),
-                                        jnp.asarray(valid))
+            probs, pred = slot.infer_program(bucket)(
+                slot.pack, jnp.asarray(x), jnp.asarray(valid))
             probs = np.asarray(probs)
             pred = np.asarray(pred)
         finally:
@@ -1067,10 +1103,12 @@ class BCPNNService:
                 if inj is not None:
                     inj.raise_if("fold-raise")
                 x, y = cycle_batch(items, self.feedback_batch)
-                cand = slot.learn_fn(slot.state, jnp.asarray(x),
-                                     jnp.asarray(y))
+                cand = slot.learn_program(self.feedback_batch)(
+                    slot.state, jnp.asarray(x), jnp.asarray(y))
                 if inj is not None and inj.maybe("nan-state") is not None:
                     cand = FaultInjector.corrupt_state(cand)
+            except CompileFailed:
+                raise
             except Exception:
                 # survived: this batch's labels are lost, serving and
                 # later folds continue on the unchanged state

@@ -14,6 +14,9 @@ of parsing messages:
   request (and every other pending one) was completed exceptionally so
   nothing hangs.  The service instance is dead — ``stop()`` re-raises
   the cause.
+* ``CompileFailed``    — a model's serving program failed to compile;
+  fatal to the engine (``warmup`` raises it, a worker that hits it
+  dies and its pending requests get ``WorkerDied``).
 * ``Quarantined``      — the target model's learning state tripped the
   non-finite sentinel and the slot is serving inference-only from its
   last-good snapshot; labeled feedback is refused until
@@ -22,7 +25,7 @@ of parsing messages:
   points (and by nothing else); seeing it outside a fault-injection run
   means an injector leaked into production wiring.
 
-``ServeError`` is the common base for the first four, so "any serving
+``ServeError`` is the common base for all but ``FaultInjected``, so "any serving
 failure" is one except clause.
 """
 from __future__ import annotations
@@ -90,6 +93,15 @@ class DeadlineExceeded(ServeError):
 class WorkerDied(ServeError):
     """The engine worker thread exited abnormally; pending requests were
     completed with this error so nothing hangs."""
+
+
+class CompileFailed(ServeError):
+    """A model's serving program (an inference bucket or the feedback
+    fold) failed to compile -- for instance a kernel the TPU compiler
+    refuses.  Every request of that shape would fail the same way, so
+    no containment layer survives it: ``warmup``/``start`` raise it, and
+    a worker that hits it dies, completing pending requests with
+    ``WorkerDied``."""
 
 
 class Quarantined(ServeError):

@@ -256,13 +256,15 @@ def test_tuned_blocks_consulted(tmp_path, monkeypatch):
     assert seen["block_b"] == 8 and "block_j" not in seen
 
 
-def test_interpret_env_override(monkeypatch):
+def test_interpret_follows_platform(monkeypatch):
+    """Interpret mode is chosen by the platform alone: Mosaic on a TPU,
+    the interpreter on every other backend."""
     from repro.kernels import ops
 
-    monkeypatch.setenv(ops.ENV_INTERPRET, "1")
-    assert ops._interpret() is True
-    monkeypatch.setenv(ops.ENV_INTERPRET, "0")
-    assert ops._interpret() is False
-    monkeypatch.delenv(ops.ENV_INTERPRET)
-    # memoized backend probe: same answer, no re-detection
-    assert ops._interpret() == (ops._default_backend() != "tpu")
+    # memoized backend probe: the process's real platform decides
+    assert ops._default_backend() == jax.default_backend()
+    assert ops._interpret() == (jax.default_backend() != "tpu")
+    for platform, interpreted in (("tpu", False), ("cpu", True),
+                                  ("gpu", True)):
+        monkeypatch.setattr(ops, "_default_backend", lambda p=platform: p)
+        assert ops._interpret() is interpreted

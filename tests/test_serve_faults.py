@@ -14,7 +14,7 @@ import pytest
 from repro.configs.bcpnn_models import deep_synth_spec
 from repro.core import infer, init_deep
 from repro.serve import (
-    BCPNNService, DeadlineExceeded, Fault, FaultInjected, FaultInjector,
+    BCPNNService, CompileFailed, DeadlineExceeded, Fault, FaultInjected, FaultInjector,
     Overloaded, Quarantined, WorkerDied, run_open_loop,
 )
 from repro.serve.engine import _state_finite
@@ -324,7 +324,8 @@ def test_dead_worker_fails_futures_and_raises_everywhere():
     first = svc.submit(x)               # worker blocks at slow-batch
     assert blk.entered.wait(10.0)
     pending = svc.submit(x)             # will be in flight at death
-    slot.infer_fn = _boom               # next batch kills the worker
+    for b in slot.infer_exe:            # next batch kills the worker
+        slot.infer_exe[b] = _boom
     blk.release.set()
     # every pending future completes exceptionally — nothing hangs
     with pytest.raises(WorkerDied):
@@ -339,6 +340,38 @@ def test_dead_worker_fails_futures_and_raises_everywhere():
     assert "KeyboardInterrupt" in str(ei.value)
     with pytest.raises(WorkerDied):
         svc.start()
+
+
+def _refuse(*args):
+    raise ValueError("kernel refused by the compiler")
+
+
+def test_compile_failure_raises_from_warmup():
+    spec, state = _small_net()
+    svc = BCPNNService(state, spec, max_batch=4)
+    svc._slot(None).infer_fn = jax.jit(_refuse)
+    with pytest.raises(CompileFailed, match="failed to compile"):
+        svc.start()
+
+
+def test_compile_failure_in_worker_kills_engine_instead_of_bisecting():
+    """A program that cannot compile fails every request alike: the
+    worker dies loudly rather than bisecting it into per-request
+    failures and serving on."""
+    spec, state = _small_net()
+    svc = BCPNNService(state, spec, max_batch=4)
+    svc._slot(None).infer_fn = jax.jit(_refuse)
+    svc.start(warmup=False)
+    rid = svc.submit(_x(spec))
+    with pytest.raises(WorkerDied, match="CompileFailed"):
+        svc.result(rid, timeout=30.0)
+    with pytest.raises(WorkerDied, match="CompileFailed"):
+        svc.submit(_x(spec))
+    snap = svc.snapshot()
+    assert snap["bisects"] == 0 and snap["failed"] == 0
+    assert snap["crashes"] == 0
+    with pytest.raises(WorkerDied, match="CompileFailed"):
+        svc.stop()
 
 
 def test_stop_timeout_raises_instead_of_hanging():

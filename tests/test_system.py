@@ -17,7 +17,9 @@ from repro.models import lm
 from repro.models.moe import init_moe, moe_ffn
 from repro.optim import init_opt_state
 
-ENV = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
+# The CLIs turn on JAX's persistent compilation cache; tests keep it off.
+ENV = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu",
+           JAX_ENABLE_COMPILATION_CACHE="false")
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
