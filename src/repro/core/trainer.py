@@ -21,13 +21,13 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import time
 from typing import Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import spans
 from ..checkpoint import CheckpointManager
 from .bcpnn_layer import forward
 from .network import (
@@ -310,17 +310,33 @@ class Trainer:
         already on disk.  With ``ckpt_dir`` alone the fit writes one
         final resumable checkpoint.
 
-        Returns timings (per-image latency covers the whole unsupervised
-        phase, i.e. depth * epochs passes over the data).
+        Returns ``train_ms_per_img``, the unsupervised epochs' time per
+        image and pass (depth * epochs passes over the data), and
+        ``straggler_events``, the epochs the fit's ``StepTimer`` flagged.
+
+        Spans (``repro.spans``, on while a profiler session is active):
+        ``trainer.fit`` around the call; inside it ``trainer.prepare``
+        (batching and the copies to the device), one ``trainer.epoch``
+        per epoch program call (``trainer.dispatch``, ``trainer.block``
+        and, when it saves, ``trainer.checkpoint``) and
+        ``trainer.propagate`` between layers.
         """
+        with spans.span("trainer.fit", images=int(np.shape(x_train)[0]),
+                        epochs=epochs, batch=batch):
+            return self._fit(x_train, y_train, epochs, batch, log, ckpt_dir,
+                             ckpt_every_batches, resume, on_chunk)
+
+    def _fit(self, x_train, y_train, epochs, batch, log, ckpt_dir,
+             ckpt_every_batches, resume, on_chunk) -> Dict[str, float]:
         from ..distributed.fault import StepTimer
 
-        xs_np, valid_np = _batchify_padded(np.asarray(x_train), batch)
-        ys_np, _ = _batchify_padded(np.asarray(y_train, np.int32), batch)
-        masked = bool(float(valid_np.min()) < 1.0)
-        xs = jnp.asarray(xs_np)
-        ys = jnp.asarray(ys_np)
-        valid = jnp.asarray(valid_np)
+        with spans.span("trainer.prepare"):
+            xs_np, valid_np = _batchify_padded(np.asarray(x_train), batch)
+            ys_np, _ = _batchify_padded(np.asarray(y_train, np.int32), batch)
+            masked = bool(float(valid_np.min()) < 1.0)
+            xs = jnp.asarray(xs_np)
+            ys = jnp.asarray(ys_np)
+            valid = jnp.asarray(valid_np)
         nb = int(xs.shape[0])
         if self.mesh is not None:
             n_shards = int(self.mesh.shape[self.data_axis])
@@ -346,12 +362,15 @@ class Trainer:
                 print(f"  resumed step_{step} at {cursor}")
         timer = StepTimer()
         self.timer = timer
+        unsup_s = 0.0
 
         def save(cur: FitCursor) -> None:
             if mgr is not None and ckpt_every_batches > 0:
-                mgr.save(int(self.state.step), self.state, blocking=True,
-                         extra={"spec": spec_to_dict(self.spec),
-                                "cursor": cur.to_dict()})
+                with spans.span("trainer.checkpoint"):
+                    mgr.save(int(self.state.step), self.state,
+                             blocking=True,
+                             extra={"spec": spec_to_dict(self.spec),
+                                    "cursor": cur.to_dict()})
 
         def run_epoch(fn: Callable, operands: tuple, start_b: int,
                       tag: str, cursor_at: Callable[[int], FitCursor]):
@@ -360,22 +379,28 @@ class Trainer:
             Chunking cannot change the result: the scan carries the state
             through bit-unchanged, and each step's arithmetic is pinned
             by its optimization barriers."""
+            nonlocal unsup_s
             b0 = start_b
             while b0 < nb:
                 n = (nb - b0 if ckpt_every_batches <= 0
                      else min(ckpt_every_batches, nb - b0))
-                sl = tuple(op[b0:b0 + n] for op in operands)
-                timer.start()
-                self.state = fn(self.state, *sl)
-                jax.block_until_ready(self.state)
-                timer.stop(int(self.state.step), tag=tag)
-                b0 += n
-                cur = cursor_at(b0)
-                save(cur)
+                with spans.timed("trainer.epoch", tag=tag,
+                                 batches=n) as ep:
+                    with spans.span("trainer.dispatch"):
+                        sl = tuple(op[b0:b0 + n] for op in operands)
+                        self.state = fn(self.state, *sl)
+                    with spans.span("trainer.block"):
+                        jax.block_until_ready(self.state)
+                    step = int(self.state.step)
+                    b0 += n
+                    cur = cursor_at(b0)
+                    save(cur)
+                timer.record(ep.dt, step, tag=tag)
+                if tag.startswith("unsup/"):
+                    unsup_s += ep.dt
                 if on_chunk is not None:
                     on_chunk(cur)
 
-        t0 = time.perf_counter()
         if cursor.phase == "unsupervised":
             # Greedy phases reuse the frozen representation: ``cur`` holds
             # the dataset's rates at the current layer's input, computed
@@ -383,7 +408,8 @@ class Trainer:
             # and recomputed (deterministic) up to the cursor on resume.
             cur = xs
             for l in range(cursor.layer):
-                cur = _propagate_batches(self.state, self.spec, cur, l)
+                with spans.span("trainer.propagate"):
+                    cur = _propagate_batches(self.state, self.spec, cur, l)
             for layer in range(cursor.layer, self.spec.depth):
                 first = layer == cursor.layer
                 fn = self._unsup_fn(layer, masked)
@@ -407,11 +433,10 @@ class Trainer:
                         print(f"  layer {layer + 1}/{self.spec.depth} "
                               f"unsupervised epoch {e + 1}/{epochs} done")
                 if layer + 1 < self.spec.depth:
-                    cur = _propagate_batches(self.state, self.spec, cur,
-                                             layer)
+                    with spans.span("trainer.propagate"):
+                        cur = _propagate_batches(self.state, self.spec, cur,
+                                                 layer)
             cursor = FitCursor("supervised", self.spec.depth, 0, 0)
-        jax.block_until_ready(self.state.projs[-1].w)
-        t1 = time.perf_counter()
         if cursor.phase == "supervised":
             fn = self._sup_fn(masked)
             operands = (xs, ys, valid) if masked else (xs, ys)
@@ -424,17 +449,14 @@ class Trainer:
             run_epoch(fn, operands, cursor.batch, "sup/readout",
                       sup_cursor_at)
             cursor = FitCursor("done", self.spec.depth, 0, 0)
-        jax.block_until_ready(self.state.readout.w)
-        t2 = time.perf_counter()
         if mgr is not None:
-            mgr.save(int(self.state.step), self.state, blocking=True,
-                     extra={"spec": spec_to_dict(self.spec),
-                            "cursor": cursor.to_dict()})
+            with spans.span("trainer.checkpoint"):
+                mgr.save(int(self.state.step), self.state, blocking=True,
+                         extra={"spec": spec_to_dict(self.spec),
+                                "cursor": cursor.to_dict()})
         n_img = int(valid_np.sum())
         return {
-            "unsup_s": t1 - t0,
-            "sup_s": t2 - t1,
-            "train_ms_per_img": 1e3 * (t1 - t0)
+            "train_ms_per_img": 1e3 * unsup_s
             / max(1, n_img * epochs * self.spec.depth),
             "straggler_events": float(len(timer.events)),
         }

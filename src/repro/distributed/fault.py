@@ -64,6 +64,12 @@ class StepTimer:
                 f"with start() before it is closed")
         dt = time.perf_counter() - self._t0
         self._t0 = None
+        return self.record(dt, step, tag)
+
+    def record(self, dt: float, step: int, tag: Optional[str] = None) -> float:
+        """Add one step of ``dt`` seconds timed elsewhere (the serving
+        engine's and the trainer's step spans read the clock once for
+        both the span and this detector)."""
         hist = self._times  # already at most `window` entries
         if len(hist) >= 8:
             med = float(np.median(hist))

@@ -70,6 +70,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import spans
 from ..core.bcpnn_layer import INFER_DTYPES, validate_patchy_state
 from ..core.network import (
     as_spec, infer_packed, online_learn_step, pack_state,
@@ -398,15 +399,19 @@ class BCPNNService:
         # weights (InferParams), not the fp32 learning state: fp32 packs
         # alias the state (bit-identical to infer()), bf16/int8 packs are
         # re-derived only when a fold mutates the state.
-        infer_fn = jax.jit(lambda pk, x, v, _spec=spec:
-                           infer_packed(pk, _spec, x, valid=v))
+        # Named programs: the device trace reads ``jit_serve_infer`` and
+        # ``jit_serve_fold``.
+        def serve_infer(pk, x, v):
+            return infer_packed(pk, spec, x, valid=v)
+
         if self.learn_stack:
-            learn_fn = jax.jit(lambda st, x, y, _spec=spec:
-                               online_learn_step(st, _spec, x, y,
-                                                 learn_stack=True))
+            def serve_fold(st, x, y):
+                return online_learn_step(st, spec, x, y, learn_stack=True)
         else:
-            learn_fn = jax.jit(lambda st, x, y, _spec=spec:
-                               supervised_readout_step(st, _spec, x, y))
+            def serve_fold(st, x, y):
+                return supervised_readout_step(st, spec, x, y)
+        infer_fn = jax.jit(serve_infer)
+        learn_fn = jax.jit(serve_fold)
         slot = _ModelSlot(
             name=name, state=state, spec=spec,
             batcher=MicroBatcher(self._buckets, max_wait_s=self._max_wait_s,
@@ -854,10 +859,11 @@ class BCPNNService:
                 if not self._control:
                     return
                 op = self._control.popleft()
-            try:
-                op.result = op.fn()
-            except Exception as e:
-                op.error = e
+            with spans.span("engine.control"):
+                try:
+                    op.result = op.fn()
+                except Exception as e:
+                    op.error = e
             op.done.set()
 
     def _note_crash(self, e: Exception) -> None:
@@ -916,22 +922,24 @@ class BCPNNService:
                 if best_key is None or key < best_key:
                     best_key, best_i = key, i
         if best_key is None:
-            self._work.wait(self._poll_s)
+            with spans.span("engine.wait"):
+                self._work.wait(self._poll_s)
             self._work.clear()
             return [], None
-        slot = self._slots[self._order[(self._cursor + best_i) % n]]
-        self._adapt(slot)
-        group = slot.batcher.next_group(
-            timeout_s=0.0,
-            target=(slot.target_bucket if self.adaptive_buckets
-                    else None))
-        if not group:
-            return [], None
-        self._cursor = (self._cursor + best_i + 1) % n
-        start = max(slot.vft, self._vclock)
-        self._vclock = start
-        slot.vft = start + len(group) * slot.cost / slot.weight
-        live = self._shed_expired(slot, group)
+        with spans.span("engine.schedule", cpu=True):
+            slot = self._slots[self._order[(self._cursor + best_i) % n]]
+            self._adapt(slot)
+            group = slot.batcher.next_group(
+                timeout_s=0.0,
+                target=(slot.target_bucket if self.adaptive_buckets
+                        else None))
+            if not group:
+                return [], None
+            self._cursor = (self._cursor + best_i + 1) % n
+            start = max(slot.vft, self._vclock)
+            self._vclock = start
+            slot.vft = start + len(group) * slot.cost / slot.weight
+            live = self._shed_expired(slot, group)
         if not live:
             # whole group expired; rescan from the advanced cursor on
             # the next loop pass
@@ -1026,30 +1034,50 @@ class BCPNNService:
         bucket = pick_bucket(len(group), self._buckets)
         inj = self.fault_injector
         self._batch_seq += 1
-        self.step_timer.start()
+        seq = self._batch_seq
+        g = spans.timed("engine.group")
         try:
-            if inj is not None:
-                f = inj.maybe("slow-batch")
-                if f is not None:
-                    time.sleep(f.delay_s)  # injected straggler
-                k = inj.maybe("engine-kill")
-                if k is not None:
-                    # BaseException: skips every supervision layer and
-                    # lands in _die — the whole engine goes down with
-                    # this batch in flight (router chaos soak fodder)
-                    raise EngineKilled(
-                        f"injected engine-kill (invocation {k.index})")
-                inj.check_group([r.id for r in group])
-                inj.raise_if("infer-raise")
-            x, valid = pad_group([r.x for r in group], bucket)
-            probs, pred = slot.infer_program(bucket)(
-                slot.pack, jnp.asarray(x), jnp.asarray(valid))
-            probs = np.asarray(probs)
-            pred = np.asarray(pred)
+            with g:
+                if g.on:
+                    g.set(seq=seq, model=slot.name, bucket=bucket,
+                          n=len(group), rid_lo=min(r.id for r in group),
+                          rid_hi=max(r.id for r in group))
+                if inj is not None:
+                    f = inj.maybe("slow-batch")
+                    if f is not None:
+                        time.sleep(f.delay_s)  # injected straggler
+                    k = inj.maybe("engine-kill")
+                    if k is not None:
+                        # BaseException: skips every supervision layer
+                        # and lands in _die — the whole engine goes down
+                        # with this batch in flight (router chaos soak
+                        # fodder)
+                        raise EngineKilled(
+                            f"injected engine-kill (invocation {k.index})")
+                    inj.check_group([r.id for r in group])
+                    inj.raise_if("infer-raise")
+                with spans.span("engine.pad", cpu=True):
+                    x, valid = pad_group([r.x for r in group], bucket)
+                    x, valid = jnp.asarray(x), jnp.asarray(valid)
+                with spans.span("engine.dispatch") as d:
+                    probs, pred = slot.infer_program(bucket)(
+                        slot.pack, x, valid)
+                if d.on:
+                    g.set(wait_s=len(group) * d.t0
+                          - sum(r.enqueue_t for r in group))
+                with spans.span("engine.readback"):
+                    probs = np.asarray(probs)
+                    pred = np.asarray(pred)
+                with spans.span("engine.complete", cpu=True):
+                    self._complete(slot, group, bucket, probs, pred)
         finally:
             # even a failing batch is a timed step: injected or genuine
             # stragglers surface as events attributed to this model
-            self.step_timer.stop(self._batch_seq, tag=slot.name)
+            self.step_timer.record(g.dt, seq, tag=slot.name)
+
+    def _complete(self, slot: _ModelSlot, group: List[Request], bucket: int,
+                  probs: np.ndarray, pred: np.ndarray) -> None:
+        """Resolve every request of a served group."""
         t_done = time.perf_counter()
         slot.metrics.record_batch(n_valid=len(group), bucket=bucket)
         for i, r in enumerate(group):
@@ -1098,40 +1126,54 @@ class BCPNNService:
                          for _ in range(min(len(slot.feedback),
                                             self.feedback_batch))]
             self._fb_cursor = (j + 1) % n
-            inj = self.fault_injector
-            try:
+            with spans.span("engine.fold") as sp:
+                sp.set(model=slot.name, n=len(items))
+                if self._fold(slot, items):
+                    sp.set(fold=slot.metrics.learn_steps)
+            return
+
+    def _fold(self, slot: _ModelSlot, items: list) -> bool:
+        """One feedback fold of ``items`` into ``slot``, its post-fold
+        check and the repack of the serving weights; False when the fold
+        raised or was quarantined."""
+        inj = self.fault_injector
+        try:
+            with spans.span("engine.fold.learn"):
                 if inj is not None:
                     inj.raise_if("fold-raise")
                 x, y = cycle_batch(items, self.feedback_batch)
                 cand = slot.learn_program(self.feedback_batch)(
                     slot.state, jnp.asarray(x), jnp.asarray(y))
-                if inj is not None and inj.maybe("nan-state") is not None:
-                    cand = FaultInjector.corrupt_state(cand)
-            except CompileFailed:
-                raise
-            except Exception:
-                # survived: this batch's labels are lost, serving and
-                # later folds continue on the unchanged state
-                slot.metrics.record_crash()
-                slot.metrics.record_feedback_dropped(len(items))
-                return
-            if not _state_finite(cand):
-                # Quarantine: the candidate is never installed, so the
-                # slot keeps serving from ``last_good`` unchanged — the
-                # explicit restore makes the rollback contract literal
-                # (and bitwise-checkable, analysis contract
-                # ``quarantine-rollback``).
-                slot.metrics.record_quarantine()
-                slot.metrics.record_feedback_dropped(len(items))
-                slot.state = slot.last_good
-                slot.quarantined = True
-                return
-            slot.state = cand
-            slot.last_good = cand
-            # THE fold boundary: the fold (and any struct_every rewire
-            # inside it) just mutated the fp32 state, so the packed
-            # serving weights are re-derived here — stale int8 scales or
-            # bf16 casts never outlive a fold.
+            if inj is not None and inj.maybe("nan-state") is not None:
+                cand = FaultInjector.corrupt_state(cand)
+        except CompileFailed:
+            raise
+        except Exception:
+            # survived: this batch's labels are lost, serving and
+            # later folds continue on the unchanged state
+            slot.metrics.record_crash()
+            slot.metrics.record_feedback_dropped(len(items))
+            return False
+        with spans.span("engine.fold.check"):
+            finite = _state_finite(cand)
+        if not finite:
+            # Quarantine: the candidate is never installed, so the
+            # slot keeps serving from ``last_good`` unchanged — the
+            # explicit restore makes the rollback contract literal
+            # (and bitwise-checkable, analysis contract
+            # ``quarantine-rollback``).
+            slot.metrics.record_quarantine()
+            slot.metrics.record_feedback_dropped(len(items))
+            slot.state = slot.last_good
+            slot.quarantined = True
+            return False
+        slot.state = cand
+        slot.last_good = cand
+        # THE fold boundary: the fold (and any struct_every rewire
+        # inside it) just mutated the fp32 state, so the packed
+        # serving weights are re-derived here — stale int8 scales or
+        # bf16 casts never outlive a fold.
+        with spans.span("engine.fold.repack"):
             slot.repack()
-            slot.metrics.record_learn(len(items))
-            return
+        slot.metrics.record_learn(len(items))
+        return True

@@ -391,6 +391,9 @@ def test_stop_timeout_raises_instead_of_hanging():
 # ------------------------------------------------------------ stragglers --
 
 def test_injected_slow_batch_surfaces_as_attributed_straggler():
+    """The injected delay sleeps inside the group's timed span, so the
+    batch that drew it is an event of at least that delay, attributed
+    to its model, whatever other events the machine's load adds."""
     spec, state = _small_net()
     inj = FaultInjector(seed=0, schedule={"slow-batch": {10}},
                         slow_ms=150.0)
@@ -402,8 +405,14 @@ def test_injected_slow_batch_surfaces_as_attributed_straggler():
             svc.classify(x, timeout=30.0)
         snap = svc.snapshot()
         assert snap["straggler_events"] >= 1.0
-        ev = [e for e in svc.step_timer.events if e.get("tag") == "default"]
-        assert ev and ev[0]["time"] >= 0.14
+        (fired,) = [f for f in inj.events if f.point == "slow-batch"]
+        # one slow-batch draw per microbatch; batch sequence numbers
+        # start at 1
+        seq = fired.index + 1
+        ev = [e for e in svc.step_timer.events if e["step"] == seq]
+        assert len(ev) == 1
+        assert ev[0]["tag"] == "default"
+        assert ev[0]["time"] >= fired.delay_s == 0.15
     finally:
         svc.stop()
 
