@@ -138,6 +138,32 @@ def is_compact(spec: ProjSpec) -> bool:
     return spec.compact
 
 
+def update_kernel(spec: ProjSpec) -> str:
+    """The fused kernel ``fused_learn`` runs for ``spec``: the compact
+    kernel for a compact-resident projection, the patchy kernel for
+    patchy-trace plasticity, the dense ``bcpnn_update`` otherwise."""
+    if is_compact(spec):
+        return "compact_update"
+    if is_patchy(spec) and spec.patchy_traces:
+        return "patchy_update"
+    return "bcpnn_update"
+
+
+def learn_path(spec: ProjSpec, masked: bool = False) -> str:
+    """The update path one step of ``learn`` (``masked``: of
+    ``learn_masked``) runs for ``spec``: a fused kernel's name, or
+    ``"jnp"``.  The one decision both dispatches and the trainer's
+    ``trainer.epoch`` span read.  Only the dense kernel takes a runtime
+    row count, so the masked step of a compact or patchy-trace projection
+    keeps the jnp stats."""
+    if spec.backend != "pallas":
+        return "jnp"
+    kernel = update_kernel(spec)
+    if masked and kernel != "bcpnn_update":
+        return "jnp"
+    return kernel
+
+
 def _compact_ops():
     # Lazy for the same reason as _pallas_ops: core.compact imports this
     # module for the Projection pytree type.
@@ -459,16 +485,31 @@ def masked_inputs(x: jax.Array, y: jax.Array, valid: jax.Array):
 
 def learn_masked(proj: Projection, spec: ProjSpec, x: jax.Array,
                  y: jax.Array, valid: jax.Array) -> Projection:
-    """Plasticity step over a zero-padded tail batch: batch stats divide
-    by the number of GENUINE rows (``valid`` 0/1 per row), so pad slots
-    are inert rather than diluting the traces.
+    """Plasticity step over a zero-padded batch: batch stats divide by the
+    number of GENUINE rows (``valid`` 0/1 per row), so pad slots are inert
+    rather than diluting the traces.
 
-    Scope: like the data-parallel steps, this always computes stats on
-    the jnp path even for ``backend="pallas"`` specs — the fused kernels
-    bake the batch size into their grid as a static divisor, so a traced
-    valid count cannot flow through them.  Only the tail batch of a fit
-    takes this path (whole batches keep the backend dispatch of
-    ``learn`` bit-for-bit)."""
+    A fit whose data does not divide the batch runs this on EVERY step of
+    its masked epoch program, not only on the tail.  On the pallas backend
+    a dense or dense-resident patchy projection takes the fused update
+    kernel with the real row count as a runtime operand, like ``learn``;
+    with valid all ones it computes exactly what ``learn`` does (``x * 1``
+    is exact and ``n`` is the batch size).  Compact and patchy-trace
+    projections, whose kernels bake a static divisor, and the jnp backend
+    compute the stats in jnp (``_learn_masked_jnp``).  ``learn_path(spec,
+    masked=True)`` names the path."""
+    if learn_path(spec, masked=True) == "bcpnn_update":
+        xv, yv, n = masked_inputs(x, y, valid)
+        return _pallas_ops().fused_learn(proj, spec, xv, yv, n=n)
+    return _learn_masked_jnp(proj, spec, x, y, valid)
+
+
+def _learn_masked_jnp(proj: Projection, spec: ProjSpec, x: jax.Array,
+                      y: jax.Array, valid: jax.Array) -> Projection:
+    """The jnp stats of ``learn_masked``, whatever the backend: the
+    reference of the masked step, and what the data-parallel steps mirror
+    (distributed/data_parallel.py), so padded fits stay bit-exact across
+    meshes."""
     xv, yv, n = masked_inputs(x, y, valid)
     xv, yv = jax.lax.optimization_barrier((xv, yv))
     xm = jnp.sum(xv, axis=0) / n

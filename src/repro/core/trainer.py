@@ -21,7 +21,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Callable, Dict, Optional
+from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -29,7 +29,7 @@ import numpy as np
 
 from .. import spans
 from ..checkpoint import CheckpointManager
-from .bcpnn_layer import forward
+from .bcpnn_layer import ProjSpec, forward, learn_path
 from .network import (
     DeepState,
     NetworkSpec,
@@ -140,9 +140,11 @@ def _train_projection_epoch_masked(state: DeepState, spec: NetworkSpec,
     """The masked twin of ``_train_projection_epoch``: ``valid`` (nb, B)
     marks genuine rows, so the zero-padded tail batch divides its stats
     by the REAL row count instead of diluting the traces (or, before the
-    pad existed at all, being silently dropped).  Only fits whose data
-    does not divide the batch take this program — whole-batch fits keep
-    the unmasked epoch (and its fused-kernel dispatch) bit-for-bit."""
+    pad existed at all, being silently dropped).  Fits whose data does
+    not divide the batch run every step of every epoch here; on the
+    pallas backend those steps take the fused update kernel with the row
+    count as a runtime operand (``learn_masked``).  Whole-batch fits keep
+    the unmasked epoch bit-for-bit."""
     def body(st, hv):
         h, v = hv
         return train_projection_step(st, spec, h, layer, valid=v), None
@@ -227,7 +229,7 @@ class Trainer:
         self.mesh = mesh
         self.data_axis = data_axis
         self.timer = None  # the last fit's StepTimer
-        self._epoch_cache: Dict[tuple, Callable] = {}
+        self._epoch_cache: Dict[tuple, Tuple[Callable, str]] = {}
         if mesh is not None:
             # Fail at construction, not mid-fit: every projection the DP
             # programs touch needs whole post-HCs per shard.
@@ -242,9 +244,15 @@ class Trainer:
         self.state = init_deep(self.spec, jax.random.PRNGKey(seed))
 
     # -------------------------------------------------- epoch programs --
-    def _unsup_fn(self, layer: int, masked: bool) -> Callable:
+    def _learn(self, pspec: ProjSpec, masked: bool) -> str:
+        """The update path an epoch program's learn steps dispatch: the
+        data-parallel steps always compute jnp stats."""
+        return "jnp" if self.mesh is not None else learn_path(pspec, masked)
+
+    def _unsup_fn(self, layer: int, masked: bool) -> Tuple[Callable, str]:
         """Epoch program for one greedy phase — single-device jit or the
-        mesh's shard_map scan, cached per (layer, masked)."""
+        mesh's shard_map scan, cached per (layer, masked) with the update
+        path its learn steps take."""
         key = ("unsup", layer, masked)
         if key not in self._epoch_cache:
             if self.mesh is None:
@@ -260,10 +268,11 @@ class Trainer:
                 fn = make_data_parallel_projection_epoch(
                     self.spec, self.mesh, layer=layer, axis=self.data_axis,
                     masked=masked)
-            self._epoch_cache[key] = fn
+            self._epoch_cache[key] = (
+                fn, self._learn(self.spec.projs[layer], masked))
         return self._epoch_cache[key]
 
-    def _sup_fn(self, masked: bool) -> Callable:
+    def _sup_fn(self, masked: bool) -> Tuple[Callable, str]:
         key = ("sup", masked)
         if key not in self._epoch_cache:
             if self.mesh is None:
@@ -278,7 +287,8 @@ class Trainer:
                     make_data_parallel_supervised_epoch)
                 fn = make_data_parallel_supervised_epoch(
                     self.spec, self.mesh, axis=self.data_axis, masked=masked)
-            self._epoch_cache[key] = fn
+            self._epoch_cache[key] = (fn, self._learn(self.spec.readout,
+                                                      masked))
         return self._epoch_cache[key]
 
     def fit(
@@ -318,7 +328,9 @@ class Trainer:
         ``trainer.fit`` around the call; inside it ``trainer.prepare``
         (batching and the copies to the device), one ``trainer.epoch``
         per epoch program call (``trainer.dispatch``, ``trainer.block``
-        and, when it saves, ``trainer.checkpoint``) and
+        and, when it saves, ``trainer.checkpoint``; its arg ``learn``
+        names the update path of the program's learn steps,
+        ``learn_path``) and
         ``trainer.propagate`` between layers.
         """
         with spans.span("trainer.fit", images=int(np.shape(x_train)[0]),
@@ -372,20 +384,22 @@ class Trainer:
                              extra={"spec": spec_to_dict(self.spec),
                                     "cursor": cur.to_dict()})
 
-        def run_epoch(fn: Callable, operands: tuple, start_b: int,
-                      tag: str, cursor_at: Callable[[int], FitCursor]):
+        def run_epoch(program: Tuple[Callable, str], operands: tuple,
+                      start_b: int, tag: str,
+                      cursor_at: Callable[[int], FitCursor]):
             """One epoch from batch ``start_b``, in checkpoint-delimited
             chunks (the whole epoch at once when not checkpointing).
             Chunking cannot change the result: the scan carries the state
             through bit-unchanged, and each step's arithmetic is pinned
             by its optimization barriers."""
             nonlocal unsup_s
+            fn, learn = program
             b0 = start_b
             while b0 < nb:
                 n = (nb - b0 if ckpt_every_batches <= 0
                      else min(ckpt_every_batches, nb - b0))
-                with spans.timed("trainer.epoch", tag=tag,
-                                 batches=n) as ep:
+                with spans.timed("trainer.epoch", tag=tag, batches=n,
+                                 learn=learn) as ep:
                     with spans.span("trainer.dispatch"):
                         sl = tuple(op[b0:b0 + n] for op in operands)
                         self.state = fn(self.state, *sl)
@@ -412,7 +426,7 @@ class Trainer:
                     cur = _propagate_batches(self.state, self.spec, cur, l)
             for layer in range(cursor.layer, self.spec.depth):
                 first = layer == cursor.layer
-                fn = self._unsup_fn(layer, masked)
+                program = self._unsup_fn(layer, masked)
                 operands = (cur, valid) if masked else (cur,)
                 for e in range(cursor.epoch if first else 0, epochs):
                     start_b = cursor.batch if first and e == cursor.epoch \
@@ -427,7 +441,7 @@ class Trainer:
                             return FitCursor("unsupervised", layer + 1, 0, 0)
                         return FitCursor("supervised", self.spec.depth, 0, 0)
 
-                    run_epoch(fn, operands, start_b,
+                    run_epoch(program, operands, start_b,
                               f"unsup/L{layer}/e{e}", cursor_at)
                     if log:
                         print(f"  layer {layer + 1}/{self.spec.depth} "
@@ -438,7 +452,7 @@ class Trainer:
                                                  layer)
             cursor = FitCursor("supervised", self.spec.depth, 0, 0)
         if cursor.phase == "supervised":
-            fn = self._sup_fn(masked)
+            program = self._sup_fn(masked)
             operands = (xs, ys, valid) if masked else (xs, ys)
 
             def sup_cursor_at(b):
@@ -446,7 +460,7 @@ class Trainer:
                     return FitCursor("supervised", self.spec.depth, 0, b)
                 return FitCursor("done", self.spec.depth, 0, 0)
 
-            run_epoch(fn, operands, cursor.batch, "sup/readout",
+            run_epoch(program, operands, cursor.batch, "sup/readout",
                       sup_cursor_at)
             cursor = FitCursor("done", self.spec.depth, 0, 0)
         if mgr is not None:

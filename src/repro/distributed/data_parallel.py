@@ -30,8 +30,11 @@ the distributed win of the compact layout.
 Scope: the steps run the jnp reference compute path regardless of
 ``ProjSpec.backend`` (the fused Pallas kernels tile their grids in ways
 that reassociate accumulation, so a kernel-fused DP step is a TPU
-follow-up — see ROADMAP).  The readout projection (a single output HC)
-replicates its tiny learn instead of sharding it.
+follow-up — see ROADMAP); the masked steps mirror
+``core.bcpnn_layer._learn_masked_jnp``, the jnp stats of the masked
+learn, even where the single-device masked step takes the kernel.  The
+readout projection (a single output HC) replicates its tiny learn
+instead of sharding it.
 """
 from __future__ import annotations
 
@@ -148,7 +151,7 @@ def _learn_sharded(proj: Projection, pspec: ProjSpec, xf: jax.Array,
     fold applies the identical ops as the single-device jnp learn.
 
     ``valid`` (optional, (B,) 0/1, replicated) is the zero-padded
-    tail-batch mask: it mirrors ``core.bcpnn_layer.learn_masked`` —
+    tail-batch mask: it mirrors ``core.bcpnn_layer._learn_masked_jnp`` —
     pad rows are zeroed before any stat, and every divisor is the real
     row count.  The column slice of the masked full activations equals
     the masked column slice elementwise, so the disjoint-support
@@ -182,9 +185,9 @@ def _learn_replicated(proj: Projection, pspec: ProjSpec, xf: jax.Array,
                       yf: jax.Array, valid=None) -> Projection:
     """Tiny projections (the single-HC readout) learn replicated: every
     device runs the identical full gemm — trivially bit-exact."""
-    from ..core.bcpnn_layer import _learn_jnp, learn_masked
+    from ..core.bcpnn_layer import _learn_jnp, _learn_masked_jnp
     if valid is not None:
-        return learn_masked(proj, pspec, xf, yf, valid)
+        return _learn_masked_jnp(proj, pspec, xf, yf, valid)
     return _learn_jnp(proj, pspec, xf, yf)
 
 

@@ -2,9 +2,9 @@
 
 One kernel performs, per (Ni, Nj) tile of the projection:
 
-    co      = XᵀY / B                    (MXU, contraction over batch)
+    co      = XᵀY / n                    (MXU, contraction over batch)
     p_ij'   = (1-α)·p_ij + α·co          (trace EMA)
-    w       = (log p_ij' − log p_i − log p_j) · mask   (Bayesian weights)
+    w       = (log p_ij' − log p_i − log p_j) [· mask]   (Bayesian weights)
 
 On the FPGA these are three pipeline stages connected by FIFOs fed from
 four partitioned HBM channels (paper Opt #3); here each (ti, tj) tile of
@@ -13,13 +13,22 @@ trace and the weight matrix never make an extra HBM round-trip.
 
 Grid = (Ni/ti, Nj/tj, B/tk) over the PADDED shapes, contraction
 innermost.  Pad semantics (DESIGN.md §7): pad batch rows of x/y are zero,
-so they add nothing to XᵀY, and the kernel divides by the REAL batch
-size — the co-activation EMA is exact.  Pad rows/columns of pij and mask
-are zero, producing inert outputs that are sliced off.
+so they add nothing to XᵀY.  The divisor ``n`` is a runtime operand, the
+number of GENUINE rows: the plain call passes the batch size, and the
+masked tail-batch learn (``core.bcpnn_layer.learn_masked``) passes its
+real row count after zeroing the pad rows, so one compiled kernel serves
+every step of a fit whose data does not divide the batch.  Pad
+rows/columns of pij and mask are zero, producing inert outputs that are
+sliced off.
+
+The unit mask is an optional operand: a dense projection's mask is all
+ones by construction, so its caller passes ``mask=None`` and the kernel
+neither streams nor multiplies one (a static flag of the kernel).
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -30,8 +39,13 @@ from .padding import pad_axis
 from .tiling import SUBLANE, lane_multiple, pad_spec
 
 
-def _kernel(x_ref, y_ref, pij_ref, lpi_ref, lpj_ref, mask_ref, alpha_ref,
-            pij_out_ref, w_out_ref, acc_ref, *, k_steps: int, batch: int, eps: float):
+def _kernel(*refs, k_steps: int, eps: float, masked: bool):
+    if masked:
+        (x_ref, y_ref, pij_ref, lpi_ref, lpj_ref, alpha_ref, n_ref, mask_ref,
+         pij_out_ref, w_out_ref, acc_ref) = refs
+    else:
+        (x_ref, y_ref, pij_ref, lpi_ref, lpj_ref, alpha_ref, n_ref,
+         pij_out_ref, w_out_ref, acc_ref) = refs
     k = pl.program_id(2)
 
     @pl.when(k == 0)
@@ -48,12 +62,12 @@ def _kernel(x_ref, y_ref, pij_ref, lpi_ref, lpj_ref, mask_ref, alpha_ref,
     @pl.when(k == k_steps - 1)
     def _epilogue():
         alpha = alpha_ref[0, 0]
-        co = acc_ref[...] / batch
+        co = acc_ref[...] / n_ref[0, 0]
         new_pij = (1.0 - alpha) * pij_ref[...] + alpha * co
         pij_out_ref[...] = new_pij
         logp = jnp.log(jnp.clip(new_pij, eps * eps, 1.0))
         w = logp - (lpi_ref[...].T + lpj_ref[...])
-        w_out_ref[...] = w * mask_ref[...]
+        w_out_ref[...] = w * mask_ref[...] if masked else w
 
 
 @functools.partial(
@@ -64,10 +78,11 @@ def bcpnn_update_pallas(
     pij: jax.Array,     # (Ni, Nj)
     log_pi: jax.Array,  # (Ni,) log of updated+clipped pre marginals
     log_pj: jax.Array,  # (Nj,)
-    x: jax.Array,       # (B, Ni)
-    y: jax.Array,       # (B, Nj)
-    mask: jax.Array,    # (Ni, Nj)
+    x: jax.Array,       # (B, Ni), pad rows zero
+    y: jax.Array,       # (B, Nj), pad rows zero
+    mask: Optional[jax.Array],  # (Ni, Nj) unit mask, or None: all ones
     alpha: jax.Array,   # scalar
+    n: Optional[jax.Array] = None,  # scalar genuine-row count; None: B
     eps: float = 1e-4,
     block_i: int = 512,
     block_j: int = 512,
@@ -85,33 +100,43 @@ def bcpnn_update_pallas(
     xp = pad_axis(pad_axis(x, 1, is_.pad), 0, ks.pad)
     yp = pad_axis(pad_axis(y, 1, js.pad), 0, ks.pad)
     pijp = pad_axis(pad_axis(pij, 0, is_.pad), 1, js.pad)
-    maskp = pad_axis(pad_axis(mask, 0, is_.pad), 1, js.pad)
     lpip = pad_axis(log_pi.reshape(1, ni), 1, is_.pad)
     lpjp = pad_axis(log_pj.reshape(1, nj), 1, js.pad)
-    grid = (is_.grid, js.grid, ks.grid)
-    kern = functools.partial(_kernel, k_steps=ks.grid, batch=b, eps=eps)
+    n = b if n is None else n
+    tile = pl.BlockSpec((is_.block, js.block), lambda i, j, k: (i, j))
+    scalar = pl.BlockSpec((1, 1), lambda i, j, k: (0, 0))
+    in_specs = [
+        pl.BlockSpec((ks.block, is_.block), lambda i, j, k: (k, i)),   # x
+        pl.BlockSpec((ks.block, js.block), lambda i, j, k: (k, j)),    # y
+        tile,                                                          # pij
+        pl.BlockSpec((1, is_.block), lambda i, j, k: (0, i)),          # log_pi
+        pl.BlockSpec((1, js.block), lambda i, j, k: (0, j)),           # log_pj
+        scalar,                                                        # alpha
+        scalar,                                                        # n
+    ]
+    operands = [xp, yp, pijp, lpip, lpjp,
+                jnp.reshape(alpha, (1, 1)).astype(jnp.float32),
+                jnp.reshape(n, (1, 1)).astype(jnp.float32)]
+    if mask is not None:
+        in_specs.append(tile)
+        operands.append(pad_axis(pad_axis(mask, 0, is_.pad), 1, js.pad))
+    kern = functools.partial(_kernel, k_steps=ks.grid, eps=eps,
+                             masked=mask is not None)
     new_pij, w = pl.pallas_call(
         kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((ks.block, is_.block), lambda i, j, k: (k, i)),   # x
-            pl.BlockSpec((ks.block, js.block), lambda i, j, k: (k, j)),    # y
-            pl.BlockSpec((is_.block, js.block), lambda i, j, k: (i, j)),   # pij
-            pl.BlockSpec((1, is_.block), lambda i, j, k: (0, i)),          # log_pi
-            pl.BlockSpec((1, js.block), lambda i, j, k: (0, j)),           # log_pj
-            pl.BlockSpec((is_.block, js.block), lambda i, j, k: (i, j)),   # mask
-            pl.BlockSpec((1, 1), lambda i, j, k: (0, 0)),                  # alpha
-        ],
-        out_specs=[
-            pl.BlockSpec((is_.block, js.block), lambda i, j, k: (i, j)),
-            pl.BlockSpec((is_.block, js.block), lambda i, j, k: (i, j)),
-        ],
+        grid=(is_.grid, js.grid, ks.grid),
+        in_specs=in_specs,
+        out_specs=[tile, tile],
         out_shape=[
             jax.ShapeDtypeStruct((is_.padded, js.padded), jnp.float32),
             jax.ShapeDtypeStruct((is_.padded, js.padded), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((is_.block, js.block), jnp.float32)],
+        # p_ij' overwrites p_ij tile by tile (each output tile reads only
+        # its own input tile), so a caller whose p_ij dies here -- the
+        # epoch scan's carry -- updates it in place instead of copying
+        # the whole matrix first.
+        input_output_aliases={2: 0},
         interpret=interpret,
-    )(xp, yp, pijp, lpip, lpjp, maskp,
-      alpha.reshape(1, 1).astype(jnp.float32))
+    )(*operands)
     return new_pij[:ni, :nj], w[:ni, :nj]

@@ -24,12 +24,14 @@ Two per-projection execution choices happen here (DESIGN.md §7):
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 from ..core.bcpnn_layer import (
     InferPack, Projection, ProjSpec, expand_hc_mask, is_compact, is_patchy,
+    update_kernel,
 )
 from ..core.compact import cached_table
 from ..core.traces import Traces
@@ -95,10 +97,11 @@ def bcpnn_fwd(x: jax.Array, w: jax.Array, bias: jax.Array, n_hc: int,
                             interpret=_interpret(), **kw)
 
 
-def bcpnn_update(pij, log_pi, log_pj, x, y, mask, alpha, eps=1e-4, **kw):
+def bcpnn_update(pij, log_pi, log_pj, x, y, mask, alpha, n=None, eps=1e-4,
+                 **kw):
     kw = _blocks("bcpnn_update", kw, b=x.shape[0], ni=x.shape[1],
                  nj=y.shape[1])
-    return bcpnn_update_pallas(pij, log_pi, log_pj, x, y, mask, alpha,
+    return bcpnn_update_pallas(pij, log_pi, log_pj, x, y, mask, alpha, n,
                                eps=eps, interpret=_interpret(), **kw)
 
 
@@ -180,28 +183,46 @@ def fused_packed_forward(pack: InferPack, spec: ProjSpec,
 
 
 def fused_learn(proj: Projection, spec: ProjSpec, x: jax.Array,
-                y: jax.Array) -> Projection:
+                y: jax.Array, n: Optional[jax.Array] = None) -> Projection:
     """Kernel-fused equivalent of core.bcpnn_layer.learn.
 
     The cheap vector traces (p_i, p_j) update in plain jnp; the O(Ni·Nj)
-    joint-trace EMA + weight recompute run in the fused Pallas kernel —
-    the compact patchy kernel when the projection opted into patchy-trace
-    plasticity (DESIGN.md §7), the dense masked kernel otherwise.
+    joint-trace EMA + weight recompute run in the fused Pallas kernel
+    ``update_kernel(spec)`` names — the compact patchy kernel when the
+    projection opted into patchy-trace plasticity (DESIGN.md §7), the
+    dense kernel otherwise, which streams the unit mask only for a patchy
+    projection (a dense one's mask is all ones).
+
+    ``n`` is the genuine-row count of a zero-padded batch
+    (``learn_masked``: pad rows of ``x``/``y`` already zero); every stat
+    divides by it.  ``None`` means the whole batch, ``x.shape[0]``.  Only
+    the dense kernel takes a runtime count.
     """
+    path = update_kernel(spec)
+    if n is not None and path != "bcpnn_update":
+        raise ValueError(
+            f"fused_learn: the {path} kernel divides by the static batch "
+            f"size; a runtime row count needs the dense bcpnn_update path")
+    if n is None:
+        # the jnp reference's own mean (``_learn_jnp``): both backends then
+        # round the marginals alike, and rewires rank the same MI
+        xm, ym = jnp.mean(x, axis=0), jnp.mean(y, axis=0)
+    else:
+        xm, ym = jnp.sum(x, axis=0) / n, jnp.sum(y, axis=0) / n
     tr = proj.traces
     a = jnp.maximum(1.0 / (tr.t.astype(jnp.float32) + 1.0), spec.alpha)
-    pi = (1.0 - a) * tr.pi + a * jnp.mean(x, axis=0)
-    pj = (1.0 - a) * tr.pj + a * jnp.mean(y, axis=0)
+    pi = (1.0 - a) * tr.pi + a * xm
+    pj = (1.0 - a) * tr.pj + a * ym
     log_pi = jnp.log(jnp.clip(pi, spec.eps, 1.0))
     log_pj = jnp.log(jnp.clip(pj, spec.eps, 1.0))
-    if is_compact(spec) and proj.table is None:
+    if path == "compact_update" and proj.table is None:
         raise ValueError(
             "fused_learn: ProjSpec.compact projection carries a dense-layout "
             "state (no index-table leaf); convert it with "
             "core.compact.compactify_state (or scripts/migrate_ckpt.py) — "
             "the dense-compute reference of the compact semantics lives on "
             "the jnp backend only")
-    if is_compact(spec):
+    if path == "compact_update":
         # Scatter-free hot path: the kernel reads and writes the resident
         # compact trace/weights — zero O(Ni·Nj) work per step.
         kw = _blocks("compact_update", {}, b=x.shape[0],
@@ -210,7 +231,7 @@ def fused_learn(proj: Projection, spec: ProjSpec, x: jax.Array,
         new_pij, w = compact_update(
             tr.pij, log_pi, log_pj, x, y, proj.table, a, spec.pre.M,
             eps=spec.eps, interpret=_interpret(), **kw)
-    elif is_patchy(spec) and spec.patchy_traces:
+    elif path == "patchy_update":
         kw = _blocks("patchy_update", {}, b=x.shape[0],
                      k=spec.nact * spec.pre.M, hj=spec.post.H,
                      mj=spec.post.M)
@@ -220,9 +241,10 @@ def fused_learn(proj: Projection, spec: ProjSpec, x: jax.Array,
             spec.pre.M, spec.post.H, spec.post.M, eps=spec.eps,
             interpret=_interpret(), **kw)
     else:
-        mask_units = expand_hc_mask(proj.mask, spec)
+        mask_units = (expand_hc_mask(proj.mask, spec) if is_patchy(spec)
+                      else None)
         new_pij, w = bcpnn_update(tr.pij, log_pi, log_pj, x, y, mask_units,
-                                  a, eps=spec.eps)
+                                  a, n, eps=spec.eps)
     b = log_pj
     return Projection(
         traces=Traces(pi=pi, pj=pj, pij=new_pij, t=tr.t + 1),

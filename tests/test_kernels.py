@@ -44,11 +44,25 @@ def test_bcpnn_fwd_sweep(b, ni, hj, mj):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
 
 
-@pytest.mark.parametrize("b,ni,nj", [(8, 32, 64), (64, 256, 512),
-                                     (128, 1024, 512), (256, 512, 2048),
-                                     # hostile: prime batch/pre, odd post
-                                     (97, 251, 40), (31, 1568, 96)])
-def test_bcpnn_update_sweep(b, ni, nj):
+UPDATE_SHAPES = [(8, 32, 64), (64, 256, 512), (128, 1024, 512),
+                 (256, 512, 2048),
+                 # hostile: prime batch/pre, odd post
+                 (97, 251, 40), (31, 1568, 96)]
+# Variants beyond the masked whole-batch call: ``nomask`` passes no mask
+# operand (a dense projection: the reference multiplies by ones);
+# ``tail`` zeroes the last third of the rows and passes the genuine-row
+# count at run time (the masked tail-batch learn), with the unit mask
+# still applied; ``tail-nomask`` does both.
+UPDATE_VARIANTS = ("nomask", "tail", "tail-nomask")
+
+
+@pytest.mark.parametrize("b,ni,nj,variant", [
+    *(pytest.param(*shape, "mask", id="-".join(map(str, shape)))
+      for shape in UPDATE_SHAPES),
+    *(pytest.param(*shape, v, id="-".join(map(str, shape)) + f"-{v}")
+      for v in UPDATE_VARIANTS for shape in UPDATE_SHAPES),
+])
+def test_bcpnn_update_sweep(b, ni, nj, variant):
     k = jax.random.split(jax.random.PRNGKey(2), 6)
     pij = jax.random.uniform(k[0], (ni, nj)) * 0.01 + 1e-5
     lpi = jnp.log(jax.random.uniform(k[1], (ni,)) * 0.5 + 1e-4)
@@ -57,10 +71,25 @@ def test_bcpnn_update_sweep(b, ni, nj):
     y = jax.random.uniform(k[4], (b, nj))
     mask = (jax.random.uniform(k[5], (ni, nj)) > 0.3).astype(jnp.float32)
     alpha = jnp.asarray(0.02)
-    gp, gw = bcpnn_update(pij, lpi, lpj, x, y, mask, alpha)
-    wp, ww = ref_bcpnn_update(pij, lpi, lpj, x, y, mask, alpha)
-    np.testing.assert_allclose(np.asarray(gp), np.asarray(wp), atol=1e-6)
-    np.testing.assert_allclose(np.asarray(gw), np.asarray(ww), atol=1e-4)
+    n = b
+    if variant.startswith("tail"):
+        n = b - b // 3
+        rows = (jnp.arange(b) < n)[:, None]
+        x, y = x * rows, y * rows
+    if variant.endswith("nomask"):
+        got = bcpnn_update(pij, lpi, lpj, x, y, None, alpha,
+                           n=jnp.asarray(n, jnp.float32))
+        mask = jnp.ones((ni, nj), jnp.float32)
+    elif variant == "tail":
+        got = bcpnn_update(pij, lpi, lpj, x, y, mask, alpha,
+                           n=jnp.asarray(n, jnp.float32))
+    else:
+        got = bcpnn_update(pij, lpi, lpj, x, y, mask, alpha)
+    wp, ww = ref_bcpnn_update(pij, lpi, lpj, x[:n], y[:n], mask, alpha)
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(wp), atol=1e-6)
+    np.testing.assert_allclose(np.asarray(got[1]), np.asarray(ww), atol=1e-4)
+    if variant == "tail":  # the patchy mask still zeroes silent synapses
+        assert np.all(np.asarray(got[1])[np.asarray(mask) == 0] == 0)
 
 
 def test_fused_stages_match_core():

@@ -154,10 +154,27 @@ def test_trainer_spans(profiled):
         "sup/readout"]
     for e in epochs:
         assert e.args["batches"] == 3
+        assert e.args["learn"] == "jnp"
         assert [r.name for r in recs if r.parent == e.id] == [
             "trainer.dispatch", "trainer.block"]
     nested(recs)
     assert {r.name for r in recs} <= host_names(profiled)
+
+
+@pytest.mark.parametrize("n", [40, 32], ids=["padded-tail", "whole-batch"])
+def test_trainer_epoch_span_names_the_fused_learn(profiled, n):
+    """On the pallas backend every epoch program of a dense network, the
+    masked ones of a fit with a padded tail included, learns through the
+    fused update kernel, and its span says so."""
+    spec = deep_synth_spec(side=4, depth=1, n_classes=2, hidden_hc=2,
+                           hidden_mc=8, backend="pallas")
+    rng = np.random.default_rng(3)
+    x = rng.random((n, spec.input_geom.N)).astype(np.float32)
+    Trainer(spec, seed=0).fit(x, rng.integers(0, 2, n), epochs=1, batch=16)
+    jax.profiler.stop_trace()
+    epochs = [r for r in spans.recorded() if r.name == "trainer.epoch"]
+    assert [(r.args["tag"], r.args["learn"]) for r in epochs] == [
+        ("unsup/L0/e0", "bcpnn_update"), ("sup/readout", "bcpnn_update")]
 
 
 def test_trainer_checkpoint_spans(profiled, tmp_path):

@@ -5,8 +5,9 @@ that is described, not attached, and refuses what the chip would refuse
 (tile-splitting reshapes, unaligned slices, VMEM overruns) -- faults that
 interpret mode cannot show.  The geometries are the Table-1 widths: the
 10-class readout, Model 1's 32x128 hidden layer on its 1568-unit input,
-Model 3's 8192-unit input, and the struct variants' nact=128 compact
-layout, all at batch 128.
+Model 3's 8192-unit input (with a unit mask, and without one at a runtime
+row count), and the struct variants' nact=128 compact layout, all at
+batch 128.
 """
 import jax
 import jax.numpy as jnp
@@ -43,6 +44,13 @@ CASES = [
     ("bcpnn_update-model3", bcpnn_update_pallas,
      [((8192, 4096), F32), ((8192,), F32), ((4096,), F32), ((B, 8192), F32),
       ((B, 4096), F32), ((8192, 4096), F32), ((), F32)], {}),
+    # a dense projection's masked-epoch step: no mask operand, and the
+    # genuine-row count as a runtime scalar
+    ("bcpnn_update-model3-rows-nomask",
+     lambda pij, lpi, lpj, x, y, alpha, n, **kw: bcpnn_update_pallas(
+         pij, lpi, lpj, x, y, None, alpha, n, **kw),
+     [((8192, 4096), F32), ((8192,), F32), ((4096,), F32), ((B, 8192), F32),
+      ((B, 4096), F32), ((), F32), ((), F32)], {}),
     ("compact_forward-nact128", compact_forward,
      [((B, 1568), F32), ((32, 256, 128), F32), ((4096,), F32),
       ((32, 128), I32)], dict(mi=2)),

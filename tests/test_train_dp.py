@@ -31,8 +31,8 @@ needs_mesh = pytest.mark.skipif(
     "sets --xla_force_host_platform_device_count=2)")
 
 
-def _spec(kind="dense", depth1=True):
-    kw = dict(alpha=1e-2, backend="jnp", support_noise=2.0, noise_steps=50)
+def _spec(kind="dense", depth1=True, backend="jnp"):
+    kw = dict(alpha=1e-2, backend=backend, support_noise=2.0, noise_steps=50)
     layers = [(6, 8)] if depth1 else [(6, 8), (4, 4)]
     if kind == "dense":
         return make_network_spec(LayerGeom(12, 2), layers, 3, **kw)
@@ -83,11 +83,14 @@ def test_tail_samples_now_train_the_network():
         "are still being dropped")
 
 
-def test_learn_masked_divides_by_real_row_count():
+@pytest.mark.parametrize("backend", ["jnp", "pallas"])
+def test_learn_masked_divides_by_real_row_count(backend):
     """The masked learner on a zero-padded batch must match the unmasked
     learner on just the genuine rows: stats divide by the REAL count, not
-    the padded batch size (which would dilute every trace)."""
-    spec = _spec("dense")
+    the padded batch size (which would dilute every trace).  On the
+    pallas backend the count reaches the fused update kernel at run
+    time."""
+    spec = _spec("dense", backend=backend)
     state = init_deep(spec, jax.random.PRNGKey(0))
     proj, pspec = state.projs[0], spec.projs[0]
     rng = np.random.default_rng(7)
@@ -106,6 +109,64 @@ def test_learn_masked_divides_by_real_row_count():
                     jax.tree_util.tree_leaves(want)):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                    rtol=1e-4, atol=1e-5)
+
+
+def test_pallas_learn_masked_on_whole_batch_equals_learn():
+    """With every row genuine the masked kernel step is the unmasked one,
+    bit for bit: ``x * 1`` is exact and the runtime count is the batch."""
+    spec = _spec("dense", backend="pallas")
+    state = init_deep(spec, jax.random.PRNGKey(0))
+    proj, pspec = state.projs[0], spec.projs[0]
+    rng = np.random.default_rng(3)
+    x = rng.random((16, pspec.pre.N)).astype(np.float32)
+    y = rng.random((16, pspec.post.N)).astype(np.float32)
+    got = learn_masked(proj, pspec, x, y, np.ones(16, np.float32))
+    _assert_states_equal(got, learn(proj, pspec, x, y), "valid all ones")
+
+
+def _program_eqns(jaxpr, scope=""):
+    """``(eqn, jit)`` for every equation of a jaxpr, through call, scan and
+    cond bodies but not into Pallas kernel bodies (those run on tiles in
+    VMEM); ``jit`` names the innermost jitted function around it."""
+    for eqn in jaxpr.eqns:
+        yield eqn, scope
+        if eqn.primitive.name == "pallas_call":
+            continue
+        inner = eqn.params.get("name", scope) if eqn.primitive.name in (
+            "jit", "pjit") else scope
+        for val in eqn.params.values():
+            for sub in (val if isinstance(val, (list, tuple)) else [val]):
+                sub = getattr(sub, "jaxpr", sub)   # ClosedJaxpr -> Jaxpr
+                if hasattr(sub, "eqns"):
+                    yield from _program_eqns(sub, inner)
+
+
+@pytest.mark.parametrize("nact", [None, [4]], ids=["dense", "patchy"])
+def test_masked_epoch_program_takes_the_update_kernel(nact):
+    """The pallas masked epoch program runs the fused update kernel.  A
+    dense projection builds no (Ni, Nj) f32 unit mask for it; a
+    dense-resident patchy one still expands and streams its mask (which
+    also shows the check can see one)."""
+    from repro.core.trainer import _train_projection_epoch_masked
+
+    spec = make_network_spec(LayerGeom(12, 2), [(6, 8)], 3, alpha=1e-2,
+                             backend="pallas", nact=nact, support_noise=2.0,
+                             noise_steps=50)
+    state = init_deep(spec, jax.random.PRNGKey(0))
+    ni, nj = spec.projs[0].pre.N, spec.projs[0].post.N
+    hs = np.zeros((2, 16, ni), np.float32)
+    valid = np.ones((2, 16), np.float32)
+    eqns = list(_program_eqns(jax.make_jaxpr(
+        lambda st, h, v: _train_projection_epoch_masked(st, spec, h, v, 0))(
+            state, hs, valid).jaxpr))
+    kernels = [e for e, jit in eqns if e.primitive.name == "pallas_call"
+               and jit == "bcpnn_update_pallas"]
+    mask_broadcasts = [
+        e for e, _ in eqns if e.primitive.name == "broadcast_in_dim"
+        and e.outvars[0].aval.dtype == np.float32
+        and int(np.prod(e.outvars[0].aval.shape)) == ni * nj]
+    assert len(kernels) == 1
+    assert bool(mask_broadcasts) == (nact is not None)
 
 
 def test_whole_batch_fit_keeps_the_unmasked_program():
