@@ -29,7 +29,7 @@ import numpy as np
 
 from .. import spans
 from ..checkpoint import CheckpointManager
-from .bcpnn_layer import ProjSpec, forward, learn_path
+from .bcpnn_layer import ProjSpec, forward, is_patchy, learn_path
 from .network import (
     DeepState,
     NetworkSpec,
@@ -78,6 +78,37 @@ class FitCursor:
     def from_dict(cls, d: dict) -> "FitCursor":
         return cls(phase=str(d["phase"]), layer=int(d["layer"]),
                    epoch=int(d["epoch"]), batch=int(d["batch"]))
+
+
+def _rewires(spec: ProjSpec) -> bool:
+    """True when ``spec``'s learn steps rewire (``maybe_rewire`` changes
+    a mask only on a projection with a binding nact budget)."""
+    return spec.struct_every > 0 and is_patchy(spec)
+
+
+class _RewireClock:
+    """The rewire points of one greedy phase, counted on the host: the
+    projection's trace clock is read from the device once, the first time
+    a recording span asks, and then advanced by the learn steps of each
+    epoch call (one per batch)."""
+
+    def __init__(self, trainer: "Trainer", layer: int):
+        self.trainer, self.layer = trainer, layer
+        spec = trainer.spec.projs[layer]
+        self.every = spec.struct_every if _rewires(spec) else 0
+        self.t: Optional[int] = None
+
+    def take(self, n: int, on: bool) -> Optional[int]:
+        """Rewire points among the next ``n`` learn steps; None when they
+        are not being counted (no recording span has asked yet)."""
+        if self.every <= 0:
+            return 0
+        if self.t is None:
+            if not on:
+                return None
+            self.t = int(self.trainer.state.projs[self.layer].traces.t)
+        t0, self.t = self.t, self.t + n
+        return (t0 + n) // self.every - t0 // self.every
 
 
 @functools.partial(jax.jit, static_argnames=("spec", "layer"),
@@ -330,13 +361,28 @@ class Trainer:
         per epoch program call (``trainer.dispatch``, ``trainer.block``
         and, when it saves, ``trainer.checkpoint``; its arg ``learn``
         names the update path of the program's learn steps,
-        ``learn_path``) and
-        ``trainer.propagate`` between layers.
+        ``learn_path``, and ``rewires`` the structural-plasticity rewire
+        points among them, counted on the host from the trace clock) and
+        ``trainer.propagate`` between layers.  For a network that rewires,
+        ``trainer.fit`` carries ``rewired``: the (pre-HC, post-HC) mask
+        entries that changed over the fit (one host copy of each mask
+        before and after, made only while the span records).
         """
         with spans.span("trainer.fit", images=int(np.shape(x_train)[0]),
-                        epochs=epochs, batch=batch):
-            return self._fit(x_train, y_train, epochs, batch, log, ckpt_dir,
-                             ckpt_every_batches, resume, on_chunk)
+                        epochs=epochs, batch=batch) as sp:
+            before = self._struct_masks() if sp.on else {}
+            out = self._fit(x_train, y_train, epochs, batch, log, ckpt_dir,
+                            ckpt_every_batches, resume, on_chunk)
+            if before:
+                after = self._struct_masks()
+                sp.set(rewired=int(sum(np.sum(before[l] != after[l])
+                                       for l in before)))
+            return out
+
+    def _struct_masks(self) -> Dict[int, np.ndarray]:
+        """Host copies of the masks of the stack projections that rewire."""
+        return {l: np.asarray(self.state.projs[l].mask)
+                for l, p in enumerate(self.spec.projs) if _rewires(p)}
 
     def _fit(self, x_train, y_train, epochs, batch, log, ckpt_dir,
              ckpt_every_batches, resume, on_chunk) -> Dict[str, float]:
@@ -386,7 +432,8 @@ class Trainer:
 
         def run_epoch(program: Tuple[Callable, str], operands: tuple,
                       start_b: int, tag: str,
-                      cursor_at: Callable[[int], FitCursor]):
+                      cursor_at: Callable[[int], FitCursor],
+                      clock: Optional[_RewireClock] = None):
             """One epoch from batch ``start_b``, in checkpoint-delimited
             chunks (the whole epoch at once when not checkpointing).
             Chunking cannot change the result: the scan carries the state
@@ -400,6 +447,7 @@ class Trainer:
                      else min(ckpt_every_batches, nb - b0))
                 with spans.timed("trainer.epoch", tag=tag, batches=n,
                                  learn=learn) as ep:
+                    ep.set(rewires=clock.take(n, ep.on) if clock else 0)
                     with spans.span("trainer.dispatch"):
                         sl = tuple(op[b0:b0 + n] for op in operands)
                         self.state = fn(self.state, *sl)
@@ -428,6 +476,7 @@ class Trainer:
                 first = layer == cursor.layer
                 program = self._unsup_fn(layer, masked)
                 operands = (cur, valid) if masked else (cur,)
+                clock = _RewireClock(self, layer)
                 for e in range(cursor.epoch if first else 0, epochs):
                     start_b = cursor.batch if first and e == cursor.epoch \
                         else 0
@@ -442,7 +491,7 @@ class Trainer:
                         return FitCursor("supervised", self.spec.depth, 0, 0)
 
                     run_epoch(program, operands, start_b,
-                              f"unsup/L{layer}/e{e}", cursor_at)
+                              f"unsup/L{layer}/e{e}", cursor_at, clock)
                     if log:
                         print(f"  layer {layer + 1}/{self.spec.depth} "
                               f"unsupervised epoch {e + 1}/{epochs} done")

@@ -7,7 +7,8 @@ interpret mode cannot show.  The geometries are the Table-1 widths: the
 10-class readout, Model 1's 32x128 hidden layer on its 1568-unit input,
 Model 3's 8192-unit input (with a unit mask, and without one at a runtime
 row count), and the struct variants' nact=128 compact layout, all at
-batch 128.
+batch 128.  Beside the kernels, Model 3 struct's dense-trace, HC-masked
+epoch programs (nact 128, the rewire under ``lax.cond``) compile whole.
 """
 import jax
 import jax.numpy as jnp
@@ -94,3 +95,51 @@ def test_kernel_compiles_for_v5e(one_chip, name, kernel, operands, static):
     fn = jax.jit(lambda *a: kernel(*a, interpret=False, **static))
     text = fn.lower(*args).compile().as_text()
     assert "tpu_custom_call" in text, f"{name}: no Mosaic kernel emitted"
+
+
+@pytest.mark.parametrize("program", ["unsupervised", "supervised"])
+def test_model3_struct_epoch_program_compiles_for_v5e(one_chip, monkeypatch,
+                                                      program):
+    """Table-1 Model 3 with nactHi 128 and a rewire every 8 steps: the
+    masked epoch programs of a fit with a padded tail, the masked
+    ``bcpnn_update_pallas`` and the rewire's conditional in the
+    unsupervised one, ``patchy_forward`` in the supervised one."""
+    import dataclasses
+    import re
+
+    from repro.configs.bcpnn_models import MODEL3_BREAST_STRUCT
+    from repro.core.network import as_spec, init_deep
+    from repro.core.trainer import (_supervised_epoch_masked,
+                                    _train_projection_epoch_masked)
+    from repro.kernels import ops
+
+    # The wrappers pick interpret mode from the platform, which is the CPU
+    # here: build the Mosaic kernels of the described chip instead.
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    spec = as_spec(dataclasses.replace(MODEL3_BREAST_STRUCT,
+                                       backend="pallas"))
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda k: init_deep(spec, k), jax.random.PRNGKey(0)))
+
+    def arg(shape, dtype=F32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    xs, valid = arg((5, B, 8192)), arg((5, B))
+    try:
+        if program == "unsupervised":
+            fn = jax.jit(lambda s, h, v: _train_projection_epoch_masked(
+                s, spec, h, v, 0), donate_argnums=(0,))
+            lowered = fn.lower(state, xs, valid)
+        else:
+            fn = jax.jit(lambda s, x, y, v: _supervised_epoch_masked(
+                s, spec, x, y, v), donate_argnums=(0,))
+            lowered = fn.lower(state, xs, arg((5, B), I32), valid)
+        text = lowered.compile().as_text()
+    finally:
+        jax.clear_caches()
+    kernels = set(re.findall(r"%(\w+?)(?:\.\d+)? = \S.*custom-call\(",
+                             text))
+    assert "bcpnn_update_pallas" in kernels
+    assert ("patchy_forward" in kernels) == (program == "supervised")
+    assert (" conditional(" in text) == (program == "unsupervised")
