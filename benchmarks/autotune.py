@@ -88,15 +88,13 @@ def _make_operands(g: dict):
     from repro.core.compact import build_table, gather_dense, unit_indices
     nact = min(g["nact"], g["hi"])
     mask_hc = topk_mask(jax.random.uniform(k[5], (g["hi"], g["hj"])), nact)
-    mask = jnp.repeat(jnp.repeat(mask_hc, g["mi"], 0), g["mj"], 1)
     table = build_table(mask_hc, nact)
     ui = unit_indices(table, g["mi"], sentinel=ni)
     lpi = jnp.log(jnp.full((ni,), 0.5))
     lpj = jnp.log(jnp.full((nj,), 1.0 / g["mj"]))
     alpha = jnp.asarray(0.01)
-    return dict(x=x, y=y, w=w, bias=bias, pij=pij, mask=mask,
-                mask_hc=mask_hc, table=table,
-                w_c=gather_dense(w, ui, g["hj"], g["mj"]),
+    return dict(x=x, y=y, w=w, bias=bias, pij=pij, mask_hc=mask_hc,
+                table=table, w_c=gather_dense(w, ui, g["hj"], g["mj"]),
                 pij_c=gather_dense(pij, ui, g["hj"], g["mj"]),
                 lpi=lpi, lpj=lpj, alpha=alpha)
 
@@ -117,7 +115,7 @@ def _calls(g: dict, ops: dict, interpret: bool):
         "bcpnn_update": (dict(b=b, ni=ni, nj=nj), lambda kw: lambda:
                          bcpnn_update_pallas(
                              ops["pij"], ops["lpi"], ops["lpj"], ops["x"],
-                             ops["y"], ops["mask"], ops["alpha"],
+                             ops["y"], ops["mask_hc"], ops["alpha"],
                              interpret=interpret, **kw)),
         "patchy_forward": (dict(b=b, k=k_units, hj=hj, mj=mj), lambda kw:
                            lambda: patchy_forward(

@@ -142,7 +142,7 @@ def check_output_shapes() -> List[str]:
             lambda pij, lpi, lpj, xx, yy, mask, al: bcpnn_update_pallas(
                 pij, lpi, lpj, xx, yy, mask, al),
             S((ni, nj), f32), S((ni,), f32), S((nj,), f32), x,
-            S((b, nj), f32), S((ni, nj), f32), alpha),
+            S((b, nj), f32), S((hi, hj), f32), alpha),
             ((ni, nj), (ni, nj))),
         "patchy_forward": (lambda: jax.eval_shape(
             lambda xx, ww, bb, tt: patchy_forward(xx, ww, bb, tt, mi, hj, mj),

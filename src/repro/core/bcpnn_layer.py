@@ -249,18 +249,6 @@ def apply_hc_mask(w: jax.Array, mask: jax.Array, spec: ProjSpec) -> jax.Array:
     return w4.reshape(spec.pre.N, spec.post.N)
 
 
-def expand_hc_mask(mask: jax.Array, spec: ProjSpec) -> jax.Array:
-    """(Hi, Hj) HC-level mask -> materialized (Ni, Nj) unit-level mask.
-
-    Only for consumers that need the mask as a standalone operand (the
-    dense update kernel streams it per tile); a single fused broadcast,
-    not the repeat chain.  Everything else should use ``apply_hc_mask``.
-    """
-    hi, mi, hj, mj = spec.pre.H, spec.pre.M, spec.post.H, spec.post.M
-    m4 = jnp.broadcast_to(mask[:, None, :, None], (hi, mi, hj, mj))
-    return m4.reshape(spec.pre.N, spec.post.N)
-
-
 def topk_mask(scores: jax.Array, k: int) -> jax.Array:
     """Exactly-k column mask: scores (Hi, Hj) -> float {0,1} mask with
     exactly ``k`` ones per post-HC column.

@@ -18,12 +18,18 @@ number of GENUINE rows: the plain call passes the batch size, and the
 masked tail-batch learn (``core.bcpnn_layer.learn_masked``) passes its
 real row count after zeroing the pad rows, so one compiled kernel serves
 every step of a fit whose data does not divide the batch.  Pad
-rows/columns of pij and mask are zero, producing inert outputs that are
-sliced off.
+rows/columns of pij and pad rows of the mask are zero, producing inert
+outputs that are sliced off.
 
-The unit mask is an optional operand: a dense projection's mask is all
-ones by construction, so its caller passes ``mask=None`` and the kernel
-neither streams nor multiplies one (a static flag of the kernel).
+The mask is an optional operand: a dense projection's mask is all ones
+by construction, so its caller passes ``mask=None`` and the kernel
+neither reads nor multiplies one (a static flag of the kernel).  A
+patchy projection passes its (Hi, Hj) hypercolumn-level mask; Mi and Mj
+follow from the shapes.  The wrapper widens it along rows only, to
+(Ni, Hj) (1 MB at Model 3's widths), and each (ti, tj) tile widens its
+(ti, Hj) rows to unit columns in VMEM: a 0/1 product with an iota-built
+(Hj, tj) one-hot on the MXU, exact because every unit column belongs to
+at most one hypercolumn.  No O(Ni·Nj) mask exists in HBM.
 """
 from __future__ import annotations
 
@@ -39,14 +45,31 @@ from .padding import pad_axis
 from .tiling import SUBLANE, lane_multiple, pad_spec
 
 
-def _kernel(*refs, k_steps: int, eps: float, masked: bool):
+def _unit_mask(rows: jax.Array, j, tj: int, mj: int) -> jax.Array:
+    """The (ti, tj) unit mask of column tile ``j`` from the tile's (ti, Hj)
+    rows of the row-widened HC mask: unit column ``c`` belongs to post-HC
+    ``(j*tj + c) // mj``; a pad column (past Hj*mj) to none, so it reads 0.
+    """
+    hj = rows.shape[1]
+    col = j * tj + jax.lax.broadcasted_iota(jnp.int32, (hj, tj), 1)
+    lo = jax.lax.broadcasted_iota(jnp.int32, (hj, tj), 0) * mj
+    hot = jnp.where((col >= lo) & (col < lo + mj), 1.0, 0.0)
+    # each output element is one 0/1 product: exact in one bf16 pass with
+    # an f32 sum, whatever ``jax_default_matmul_precision`` asks elsewhere
+    return jnp.dot(rows.astype(jnp.bfloat16), hot.astype(jnp.bfloat16),
+                   precision=jax.lax.Precision.DEFAULT,
+                   preferred_element_type=jnp.float32)
+
+
+def _kernel(*refs, k_steps: int, eps: float, mj: Optional[int]):
+    masked = mj is not None
     if masked:
         (x_ref, y_ref, pij_ref, lpi_ref, lpj_ref, alpha_ref, n_ref, mask_ref,
          pij_out_ref, w_out_ref, acc_ref) = refs
     else:
         (x_ref, y_ref, pij_ref, lpi_ref, lpj_ref, alpha_ref, n_ref,
          pij_out_ref, w_out_ref, acc_ref) = refs
-    k = pl.program_id(2)
+    j, k = pl.program_id(1), pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
@@ -67,7 +90,9 @@ def _kernel(*refs, k_steps: int, eps: float, masked: bool):
         pij_out_ref[...] = new_pij
         logp = jnp.log(jnp.clip(new_pij, eps * eps, 1.0))
         w = logp - (lpi_ref[...].T + lpj_ref[...])
-        w_out_ref[...] = w * mask_ref[...] if masked else w
+        if masked:
+            w = w * _unit_mask(mask_ref[...], j, w.shape[1], mj)
+        w_out_ref[...] = w
 
 
 @functools.partial(
@@ -80,7 +105,7 @@ def bcpnn_update_pallas(
     log_pj: jax.Array,  # (Nj,)
     x: jax.Array,       # (B, Ni), pad rows zero
     y: jax.Array,       # (B, Nj), pad rows zero
-    mask: Optional[jax.Array],  # (Ni, Nj) unit mask, or None: all ones
+    mask: Optional[jax.Array],  # (Hi, Hj) HC mask, or None: all ones
     alpha: jax.Array,   # scalar
     n: Optional[jax.Array] = None,  # scalar genuine-row count; None: B
     eps: float = 1e-4,
@@ -117,11 +142,19 @@ def bcpnn_update_pallas(
     operands = [xp, yp, pijp, lpip, lpjp,
                 jnp.reshape(alpha, (1, 1)).astype(jnp.float32),
                 jnp.reshape(n, (1, 1)).astype(jnp.float32)]
+    mj = None
     if mask is not None:
-        in_specs.append(tile)
-        operands.append(pad_axis(pad_axis(mask, 0, is_.pad), 1, js.pad))
-    kern = functools.partial(_kernel, k_steps=ks.grid, eps=eps,
-                             masked=mask is not None)
+        hi, hj = mask.shape
+        if ni % hi or nj % hj:
+            raise ValueError(
+                f"bcpnn_update_pallas: a ({hi}, {hj}) HC mask does not tile "
+                f"a ({ni}, {nj}) projection")
+        mj = nj // hj
+        rows = jnp.repeat(mask.astype(jnp.float32), ni // hi, axis=0)
+        # Hj is the whole lane extent of the block, so any Hj is legal
+        in_specs.append(pl.BlockSpec((is_.block, hj), lambda i, j, k: (i, 0)))
+        operands.append(pad_axis(rows, 0, is_.pad))
+    kern = functools.partial(_kernel, k_steps=ks.grid, eps=eps, mj=mj)
     new_pij, w = pl.pallas_call(
         kern,
         grid=(is_.grid, js.grid, ks.grid),

@@ -30,8 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.bcpnn_layer import (
-    InferPack, Projection, ProjSpec, expand_hc_mask, is_compact, is_patchy,
-    update_kernel,
+    InferPack, Projection, ProjSpec, is_compact, is_patchy, update_kernel,
 )
 from ..core.compact import cached_table
 from ..core.traces import Traces
@@ -190,8 +189,8 @@ def fused_learn(proj: Projection, spec: ProjSpec, x: jax.Array,
     joint-trace EMA + weight recompute run in the fused Pallas kernel
     ``update_kernel(spec)`` names — the compact patchy kernel when the
     projection opted into patchy-trace plasticity (DESIGN.md §7), the
-    dense kernel otherwise, which streams the unit mask only for a patchy
-    projection (a dense one's mask is all ones).
+    dense kernel otherwise, which reads the (Hi, Hj) HC mask only for a
+    patchy projection (a dense one's mask is all ones).
 
     ``n`` is the genuine-row count of a zero-padded batch
     (``learn_masked``: pad rows of ``x``/``y`` already zero); every stat
@@ -241,10 +240,9 @@ def fused_learn(proj: Projection, spec: ProjSpec, x: jax.Array,
             spec.pre.M, spec.post.H, spec.post.M, eps=spec.eps,
             interpret=_interpret(), **kw)
     else:
-        mask_units = (expand_hc_mask(proj.mask, spec) if is_patchy(spec)
-                      else None)
-        new_pij, w = bcpnn_update(tr.pij, log_pi, log_pj, x, y, mask_units,
-                                  a, n, eps=spec.eps)
+        mask = proj.mask if is_patchy(spec) else None
+        new_pij, w = bcpnn_update(tr.pij, log_pi, log_pj, x, y, mask, a, n,
+                                  eps=spec.eps)
     b = log_pj
     return Projection(
         traces=Traces(pi=pi, pj=pj, pij=new_pij, t=tr.t + 1),

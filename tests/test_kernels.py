@@ -44,52 +44,94 @@ def test_bcpnn_fwd_sweep(b, ni, hj, mj):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
 
 
-UPDATE_SHAPES = [(8, 32, 64), (64, 256, 512), (128, 1024, 512),
-                 (256, 512, 2048),
+# (b, ni, nj), and the HC counts (hi, hj) of the masked cases' random
+# hypercolumn mask: Mi = 1 at 256 and at the prime 251, Mj = 10 and 12
+# (neither divides a lane tile), and MNIST's 1568 = 784 x 2 pre side.
+UPDATE_SHAPES = [((8, 32, 64), (16, 4)), ((64, 256, 512), (256, 8)),
+                 ((128, 1024, 512), (128, 4)), ((256, 512, 2048), (64, 16)),
                  # hostile: prime batch/pre, odd post
-                 (97, 251, 40), (31, 1568, 96)]
+                 ((97, 251, 40), (251, 4)), ((31, 1568, 96), (784, 8))]
 # Variants beyond the masked whole-batch call: ``nomask`` passes no mask
 # operand (a dense projection: the reference multiplies by ones);
 # ``tail`` zeroes the last third of the rows and passes the genuine-row
-# count at run time (the masked tail-batch learn), with the unit mask
+# count at run time (the masked tail-batch learn), with the HC mask
 # still applied; ``tail-nomask`` does both.
 UPDATE_VARIANTS = ("nomask", "tail", "tail-nomask")
 
 
-@pytest.mark.parametrize("b,ni,nj,variant", [
-    *(pytest.param(*shape, "mask", id="-".join(map(str, shape)))
-      for shape in UPDATE_SHAPES),
-    *(pytest.param(*shape, v, id="-".join(map(str, shape)) + f"-{v}")
-      for v in UPDATE_VARIANTS for shape in UPDATE_SHAPES),
-])
-def test_bcpnn_update_sweep(b, ni, nj, variant):
+def _unit_mask(mask, mi, mj):
+    """(Hi, Hj) HC mask -> (Ni, Nj) unit mask, the reference's operand."""
+    hi, hj = mask.shape
+    m4 = jnp.broadcast_to(mask[:, None, :, None], (hi, mi, hj, mj))
+    return m4.reshape(hi * mi, hj * mj)
+
+
+def _update_operands(b, ni, nj, hi, hj, tail):
     k = jax.random.split(jax.random.PRNGKey(2), 6)
     pij = jax.random.uniform(k[0], (ni, nj)) * 0.01 + 1e-5
     lpi = jnp.log(jax.random.uniform(k[1], (ni,)) * 0.5 + 1e-4)
     lpj = jnp.log(jax.random.uniform(k[2], (nj,)) * 0.5 + 1e-4)
     x = jax.random.uniform(k[3], (b, ni))
     y = jax.random.uniform(k[4], (b, nj))
-    mask = (jax.random.uniform(k[5], (ni, nj)) > 0.3).astype(jnp.float32)
-    alpha = jnp.asarray(0.02)
-    n = b
-    if variant.startswith("tail"):
-        n = b - b // 3
+    mask = (jax.random.uniform(k[5], (hi, hj)) > 0.3).astype(jnp.float32)
+    n = b - b // 3 if tail else b
+    if tail:
         rows = (jnp.arange(b) < n)[:, None]
         x, y = x * rows, y * rows
+    return pij, lpi, lpj, x, y, mask, n
+
+
+@pytest.mark.parametrize("b,ni,nj,hi,hj,variant", [
+    *(pytest.param(*shape, *hc, "mask", id="-".join(map(str, shape)))
+      for shape, hc in UPDATE_SHAPES),
+    *(pytest.param(*shape, *hc, v, id="-".join(map(str, shape)) + f"-{v}")
+      for v in UPDATE_VARIANTS for shape, hc in UPDATE_SHAPES),
+])
+def test_bcpnn_update_sweep(b, ni, nj, hi, hj, variant):
+    pij, lpi, lpj, x, y, mask, n = _update_operands(
+        b, ni, nj, hi, hj, variant.startswith("tail"))
+    alpha = jnp.asarray(0.02)
+    unit = _unit_mask(mask, ni // hi, nj // hj)
     if variant.endswith("nomask"):
         got = bcpnn_update(pij, lpi, lpj, x, y, None, alpha,
                            n=jnp.asarray(n, jnp.float32))
-        mask = jnp.ones((ni, nj), jnp.float32)
+        unit = jnp.ones((ni, nj), jnp.float32)
     elif variant == "tail":
         got = bcpnn_update(pij, lpi, lpj, x, y, mask, alpha,
                            n=jnp.asarray(n, jnp.float32))
     else:
         got = bcpnn_update(pij, lpi, lpj, x, y, mask, alpha)
-    wp, ww = ref_bcpnn_update(pij, lpi, lpj, x[:n], y[:n], mask, alpha)
+    wp, ww = ref_bcpnn_update(pij, lpi, lpj, x[:n], y[:n], unit, alpha)
     np.testing.assert_allclose(np.asarray(got[0]), np.asarray(wp), atol=1e-6)
     np.testing.assert_allclose(np.asarray(got[1]), np.asarray(ww), atol=1e-4)
     if variant == "tail":  # the patchy mask still zeroes silent synapses
-        assert np.all(np.asarray(got[1])[np.asarray(mask) == 0] == 0)
+        assert np.all(np.asarray(got[1])[np.asarray(unit) == 0] == 0)
+
+
+@pytest.mark.parametrize("b,ni,nj,hi,hj,blocks", [
+    pytest.param(8, 32, 64, 16, 4, {}, id="8-32-64"),
+    pytest.param(97, 251, 40, 251, 4, {}, id="mi1-mj10"),
+    pytest.param(31, 1568, 96, 784, 8, {}, id="mnist-pre"),
+    # Mj = 10 across four 128-lane column tiles, pad rows and columns
+    pytest.param(40, 300, 480, 100, 48,
+                 dict(block_i=128, block_j=128, block_k=16), id="mj10-tiles"),
+    # one post-HC spans two column tiles
+    pytest.param(16, 256, 512, 64, 2, dict(block_i=128, block_j=128),
+                 id="mj256-tiles"),
+])
+@pytest.mark.parametrize("tail", [False, True], ids=["whole", "tail"])
+def test_bcpnn_update_hc_mask_is_bit_exact(b, ni, nj, hi, hj, blocks, tail):
+    """Widening the HC mask per tile is the old unit-mask product, bit for
+    bit: ``p_ij'`` does not see the mask, and ``w`` is the unmasked
+    weights times the expanded 0/1 unit mask."""
+    pij, lpi, lpj, x, y, mask, n = _update_operands(b, ni, nj, hi, hj, tail)
+    alpha, n = jnp.asarray(0.02), jnp.asarray(n, jnp.float32)
+    got = bcpnn_update(pij, lpi, lpj, x, y, mask, alpha, n=n, **blocks)
+    pij_d, w_d = bcpnn_update(pij, lpi, lpj, x, y, None, alpha, n=n, **blocks)
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(pij_d))
+    np.testing.assert_array_equal(
+        np.asarray(got[1]),
+        np.asarray(w_d * _unit_mask(mask, ni // hi, nj // hj)))
 
 
 def test_fused_stages_match_core():

@@ -5,9 +5,9 @@ that is described, not attached, and refuses what the chip would refuse
 (tile-splitting reshapes, unaligned slices, VMEM overruns) -- faults that
 interpret mode cannot show.  The geometries are the Table-1 widths: the
 10-class readout, Model 1's 32x128 hidden layer on its 1568-unit input,
-Model 3's 8192-unit input (with a unit mask, and without one at a runtime
-row count), and the struct variants' nact=128 compact layout, all at
-batch 128.  Beside the kernels, Model 3 struct's dense-trace, HC-masked
+Model 3's 8192-unit input (without a mask, at the batch size and at a
+runtime row count, and with Model 3 struct's (4096, 32) HC mask), and the
+struct variants' nact=128 compact layout, all at batch 128.  Beside the kernels, Model 3 struct's dense-trace, HC-masked
 epoch programs (nact 128, the rewire under ``lax.cond``) compile whole.
 """
 import jax
@@ -23,6 +23,17 @@ from repro.kernels.quant import quant_compact_forward, quant_fwd_pallas
 
 B = 128
 F32, BF16, I8, I32 = jnp.float32, jnp.bfloat16, jnp.int8, jnp.int32
+
+
+
+def _at_highest(kernel):
+    """``kernel`` traced at ``highest`` matmul precision, as the Table-1
+    configurations run it (``matmul_precision`` in ``bench/configs/``)."""
+    def run(*args, **kw):
+        with jax.default_matmul_precision("highest"):
+            return kernel(*args, **kw)
+    return run
+
 
 # (name, kernel, operand shapes+dtypes, static kwargs)
 READOUT = dict(n_hc=1, n_mc=10)          # Table-1 readout: 1 HC x 10 classes
@@ -42,9 +53,12 @@ CASES = [
     ("quant_fwd-32x128", quant_fwd_pallas,
      [((B, 1568), F32), ((1568, 4096), I8), ((4096,), F32), ((32,), F32)],
      HIDDEN),
-    ("bcpnn_update-model3", bcpnn_update_pallas,
+    # a whole-batch step of a dense projection: no mask, n = the batch
+    ("bcpnn_update-model3",
+     lambda pij, lpi, lpj, x, y, alpha, **kw: bcpnn_update_pallas(
+         pij, lpi, lpj, x, y, None, alpha, **kw),
      [((8192, 4096), F32), ((8192,), F32), ((4096,), F32), ((B, 8192), F32),
-      ((B, 4096), F32), ((8192, 4096), F32), ((), F32)], {}),
+      ((B, 4096), F32), ((), F32)], {}),
     # a dense projection's masked-epoch step: no mask operand, and the
     # genuine-row count as a runtime scalar
     ("bcpnn_update-model3-rows-nomask",
@@ -52,6 +66,12 @@ CASES = [
          pij, lpi, lpj, x, y, None, alpha, n, **kw),
      [((8192, 4096), F32), ((8192,), F32), ((4096,), F32), ((B, 8192), F32),
       ((B, 4096), F32), ((), F32), ((), F32)], {}),
+    # Model 3 struct's masked-epoch step: the (Hi, Hj) = (4096, 32) HC mask
+    # (Mi 2, Mj 128), widened to unit columns inside each tile, at the
+    # configuration's `highest` matmul precision
+    ("bcpnn_update-model3-struct-hcmask", _at_highest(bcpnn_update_pallas),
+     [((8192, 4096), F32), ((8192,), F32), ((4096,), F32), ((B, 8192), F32),
+      ((B, 4096), F32), ((4096, 32), F32), ((), F32), ((), F32)], {}),
     ("compact_forward-nact128", compact_forward,
      [((B, 1568), F32), ((32, 256, 128), F32), ((4096,), F32),
       ((32, 128), I32)], dict(mi=2)),
@@ -127,15 +147,17 @@ def test_model3_struct_epoch_program_compiles_for_v5e(one_chip, monkeypatch,
 
     xs, valid = arg((5, B, 8192)), arg((5, B))
     try:
-        if program == "unsupervised":
-            fn = jax.jit(lambda s, h, v: _train_projection_epoch_masked(
-                s, spec, h, v, 0), donate_argnums=(0,))
-            lowered = fn.lower(state, xs, valid)
-        else:
-            fn = jax.jit(lambda s, x, y, v: _supervised_epoch_masked(
-                s, spec, x, y, v), donate_argnums=(0,))
-            lowered = fn.lower(state, xs, arg((5, B), I32), valid)
-        text = lowered.compile().as_text()
+        # the configuration's matmul precision (``bench/configs/``)
+        with jax.default_matmul_precision("highest"):
+            if program == "unsupervised":
+                fn = jax.jit(lambda s, h, v: _train_projection_epoch_masked(
+                    s, spec, h, v, 0), donate_argnums=(0,))
+                lowered = fn.lower(state, xs, valid)
+            else:
+                fn = jax.jit(lambda s, x, y, v: _supervised_epoch_masked(
+                    s, spec, x, y, v), donate_argnums=(0,))
+                lowered = fn.lower(state, xs, arg((5, B), I32), valid)
+            text = lowered.compile().as_text()
     finally:
         jax.clear_caches()
     kernels = set(re.findall(r"%(\w+?)(?:\.\d+)? = \S.*custom-call\(",

@@ -143,10 +143,11 @@ def _program_eqns(jaxpr, scope=""):
 
 @pytest.mark.parametrize("nact", [None, [4]], ids=["dense", "patchy"])
 def test_masked_epoch_program_takes_the_update_kernel(nact):
-    """The pallas masked epoch program runs the fused update kernel.  A
-    dense projection builds no (Ni, Nj) f32 unit mask for it; a
-    dense-resident patchy one still expands and streams its mask (which
-    also shows the check can see one)."""
+    """The pallas masked epoch program runs the fused update kernel and
+    builds no (Ni, Nj) f32 unit mask for it: a dense projection passes no
+    mask, and a dense-resident patchy one passes its HC mask widened along
+    rows only, (Ni, Hj), which the kernel widens to unit columns per tile.
+    ``pij`` is the kernel's one (Ni, Nj) operand."""
     from repro.core.trainer import _train_projection_epoch_masked
 
     spec = make_network_spec(LayerGeom(12, 2), [(6, 8)], 3, alpha=1e-2,
@@ -154,6 +155,7 @@ def test_masked_epoch_program_takes_the_update_kernel(nact):
                              noise_steps=50)
     state = init_deep(spec, jax.random.PRNGKey(0))
     ni, nj = spec.projs[0].pre.N, spec.projs[0].post.N
+    hj = spec.projs[0].post.H
     hs = np.zeros((2, 16, ni), np.float32)
     valid = np.ones((2, 16), np.float32)
     eqns = list(_program_eqns(jax.make_jaxpr(
@@ -166,7 +168,10 @@ def test_masked_epoch_program_takes_the_update_kernel(nact):
         and e.outvars[0].aval.dtype == np.float32
         and int(np.prod(e.outvars[0].aval.shape)) == ni * nj]
     assert len(kernels) == 1
-    assert bool(mask_broadcasts) == (nact is not None)
+    assert not mask_broadcasts
+    shapes = [tuple(v.aval.shape) for v in kernels[0].invars]
+    assert shapes.count((ni, nj)) == 1           # pij, nothing beside it
+    assert ((ni, hj) in shapes) == (nact is not None)
 
 
 def test_whole_batch_fit_keeps_the_unmasked_program():
