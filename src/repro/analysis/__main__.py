@@ -12,7 +12,7 @@ from pathlib import Path
 from typing import List
 
 # Scanned by default: everything that is source, nothing that is corpus.
-DEFAULT_ROOTS = ("src", "scripts", "benchmarks", "examples", "tests")
+DEFAULT_ROOTS = ("src", "scripts", "examples", "tests")
 BASELINE_NAME = ".analysis-baseline.json"
 
 

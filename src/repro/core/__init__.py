@@ -26,10 +26,7 @@ from .network import (
     unsupervised_layer_step,
     unsupervised_step,
 )
-from .trainer import (
-    FitCursor, Trainer, eval_batches, evaluate_padded, supervised_epoch,
-    unsupervised_epoch, unsupervised_layer_epoch,
-)
+from .trainer import FitCursor, Trainer, eval_batches, evaluate_padded
 from .head import (
     BCPNNHeadConfig,
     encode_features,
@@ -49,8 +46,7 @@ __all__ = [
     "online_learn_step", "spec_from_dict", "spec_to_dict",
     "stack_rates", "supervised_readout_step", "supervised_step",
     "train_projection_step", "unsupervised_layer_step", "unsupervised_step",
-    "FitCursor", "Trainer", "eval_batches", "evaluate_padded", "supervised_epoch",
-    "unsupervised_epoch", "unsupervised_layer_epoch",
+    "FitCursor", "Trainer", "eval_batches", "evaluate_padded",
     "BCPNNHeadConfig", "encode_features", "head_predict", "head_supervised",
     "head_unsupervised", "init_head",
 ]

@@ -13,9 +13,9 @@ recompute).  Each projection carries a ``backend`` tag in its spec:
                    production hot path.
 
 ``forward`` / ``support`` / ``learn`` below are the single dispatch
-point: every caller (the deep network engine, the trainer, benchmarks)
-routes through them, so flipping ``ProjSpec.backend`` swaps the whole
-execution stack per projection.  See DESIGN.md §3.
+point: every caller (the deep network engine, the trainer, the serving
+engine) routes through them, so flipping ``ProjSpec.backend`` swaps the
+whole execution stack per projection.  See DESIGN.md §3.
 """
 from __future__ import annotations
 

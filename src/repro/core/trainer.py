@@ -39,7 +39,6 @@ from .network import (
     spec_to_dict,
     supervised_readout_step,
     train_projection_step,
-    unsupervised_layer_step,
 )
 
 
@@ -113,24 +112,6 @@ class _RewireClock:
 
 @functools.partial(jax.jit, static_argnames=("spec", "layer"),
                    donate_argnums=(0,))
-def unsupervised_layer_epoch(state: DeepState, spec: NetworkSpec,
-                             xs: jax.Array, layer: int) -> DeepState:
-    """xs: (nbatch, B, Ni) — one unsupervised epoch on stack projection
-    ``layer``, fully on device."""
-    def body(st, x):
-        return unsupervised_layer_step(st, spec, x, layer), None
-    state, _ = jax.lax.scan(body, state, xs)
-    return state
-
-
-def unsupervised_epoch(state: DeepState, spec_or_cfg, xs: jax.Array,
-                       layer: int = 0) -> DeepState:
-    """Legacy entry point (depth-1 networks train their only projection)."""
-    return unsupervised_layer_epoch(state, as_spec(spec_or_cfg), xs, layer)
-
-
-@functools.partial(jax.jit, static_argnames=("spec", "layer"),
-                   donate_argnums=(0,))
 def _train_projection_epoch(state: DeepState, spec: NetworkSpec,
                             hs: jax.Array, layer: int) -> DeepState:
     """One epoch over PRECOMPUTED layer-input rates hs: (nbatch, B, N_l)."""
@@ -156,11 +137,6 @@ def _supervised_epoch(state: DeepState, spec: NetworkSpec, xs: jax.Array,
         return supervised_readout_step(st, spec, x, y), None
     state, _ = jax.lax.scan(body, state, (xs, ys))
     return state
-
-
-def supervised_epoch(state: DeepState, spec_or_cfg, xs: jax.Array,
-                     ys: jax.Array) -> DeepState:
-    return _supervised_epoch(state, as_spec(spec_or_cfg), xs, ys)
 
 
 @functools.partial(jax.jit, static_argnames=("spec", "layer"),

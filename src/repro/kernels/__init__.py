@@ -2,7 +2,8 @@
 
 Dense kernels run on pad-to-aligned tiling plans (tiling.py); patchy
 projections stream a compact gathered layout (patchy.py); block sizes
-come from the autotune cache (tuning.py) unless the caller overrides.
+come from those plans unless the caller passes explicit ``block_*``
+kwargs.
 """
 from .ops import (bcpnn_fwd, bcpnn_update, fused_forward, fused_learn,
                   fused_packed_forward, hc_softmax)

@@ -7,19 +7,16 @@ implementations behind `ProjSpec(backend="pallas")`: the core's dispatch
 point (core/bcpnn_layer.py, DESIGN.md §3) routes every activation /
 plasticity call of a pallas-tagged projection here, mirroring the paper's
 stream-dataflow configuration, while the pure-jnp reference path plays
-the sequential baseline (benchmarks/bench_stream_vs_seq.py).
+the sequential baseline.
 
-Two per-projection execution choices happen here (DESIGN.md §7):
-
-* **dense vs patchy** — projections with an ``nact`` connectivity budget
-  route ``fused_forward`` through the compact patchy kernels
-  (kernels/patchy.py), streaming only live pre-blocks; ``fused_learn``
-  additionally requires ``spec.patchy_traces`` (patchy plasticity is a
-  semantic choice — silent synapses hold their traces — not just a
-  schedule).
-* **block sizes** — unless the caller passes explicit ``block_*`` kwargs,
-  each wrapper consults the autotune cache (kernels/tuning.py) keyed by
-  the call's geometry and the active jax backend.
+Dense vs patchy is chosen here per projection (DESIGN.md §7): projections
+with an ``nact`` connectivity budget route ``fused_forward`` through the
+compact patchy kernels (kernels/patchy.py), streaming only live
+pre-blocks; ``fused_learn`` additionally requires ``spec.patchy_traces``
+(patchy plasticity is a semantic choice — silent synapses hold their
+traces — not just a schedule).  Block sizes come from each kernel's
+padded plan (kernels/tiling.py) unless the caller passes explicit
+``block_*`` kwargs.
 """
 from __future__ import annotations
 
@@ -34,7 +31,6 @@ from ..core.bcpnn_layer import (
 )
 from ..core.compact import cached_table
 from ..core.traces import Traces
-from . import tuning
 from .bcpnn_fwd import bcpnn_fwd_pallas
 from .bcpnn_update import bcpnn_update_pallas
 from .hc_softmax import hc_softmax_pallas
@@ -55,51 +51,20 @@ def _interpret() -> bool:
     return _default_backend() != "tpu"
 
 
-# block kwargs each wrapper accepts — guards against stale cache entries
-_KERNEL_BLOCKS = {
-    "hc_softmax": ("block_b", "block_h"),
-    "bcpnn_fwd": ("block_b", "block_j", "block_k"),
-    "bcpnn_update": ("block_i", "block_j", "block_k"),
-    "patchy_forward": ("block_b", "block_k"),
-    "patchy_update": ("block_i", "block_k"),
-    "compact_forward": ("block_b", "block_k"),
-    "compact_update": ("block_i", "block_k"),
-    "quant_fwd": ("block_b", "block_j", "block_k"),
-    "quant_patchy_forward": ("block_b", "block_k"),
-    "quant_compact_forward": ("block_b", "block_k"),
-}
-
-
-def _blocks(kernel: str, kw: dict, **dims: int) -> dict:
-    """Merge autotuned block sizes under explicit caller kwargs."""
-    if any(k.startswith("block_") for k in kw):
-        return kw
-    tuned = tuning.lookup(kernel, **dims)
-    if not tuned:
-        return kw
-    allowed = _KERNEL_BLOCKS[kernel]
-    return {**{k: v for k, v in tuned.items() if k in allowed}, **kw}
-
-
 def hc_softmax(support: jax.Array, n_hc: int, n_mc: int, gain: float = 1.0,
                **kw) -> jax.Array:
-    kw = _blocks("hc_softmax", kw, b=support.shape[0], n_hc=n_hc, n_mc=n_mc)
     return hc_softmax_pallas(support, n_hc, n_mc, gain,
                              interpret=_interpret(), **kw)
 
 
 def bcpnn_fwd(x: jax.Array, w: jax.Array, bias: jax.Array, n_hc: int,
               n_mc: int, gain: float = 1.0, **kw) -> jax.Array:
-    kw = _blocks("bcpnn_fwd", kw, b=x.shape[0], ni=x.shape[1],
-                 n_hc=n_hc, n_mc=n_mc)
     return bcpnn_fwd_pallas(x, w, bias, n_hc, n_mc, gain,
                             interpret=_interpret(), **kw)
 
 
 def bcpnn_update(pij, log_pi, log_pj, x, y, mask, alpha, n=None, eps=1e-4,
                  **kw):
-    kw = _blocks("bcpnn_update", kw, b=x.shape[0], ni=x.shape[1],
-                 nj=y.shape[1])
     return bcpnn_update_pallas(pij, log_pi, log_pj, x, y, mask, alpha, n,
                                eps=eps, interpret=_interpret(), **kw)
 
@@ -115,20 +80,14 @@ def fused_forward(proj: Projection, spec: ProjSpec, x: jax.Array) -> jax.Array:
     gather: the resident (Hj, K, Mj) weights and the persistent index
     table feed the kernel directly."""
     if is_compact(spec) and proj.table is not None:
-        kw = _blocks("compact_forward", {}, b=x.shape[0],
-                     k=spec.nact * spec.pre.M, hj=spec.post.H,
-                     mj=spec.post.M)
         return compact_forward(x, proj.w, proj.b, proj.table, spec.pre.M,
-                               spec.gain, interpret=_interpret(), **kw)
+                               spec.gain, interpret=_interpret())
     if is_patchy(spec):
-        kw = _blocks("patchy_forward", {}, b=x.shape[0],
-                     k=spec.nact * spec.pre.M, hj=spec.post.H,
-                     mj=spec.post.M)
         table = cached_table(proj.mask, spec.nact)
         return patchy_forward(
             x, proj.w, proj.b, table, spec.pre.M,
             spec.post.H, spec.post.M, spec.gain,
-            interpret=_interpret(), **kw)
+            interpret=_interpret())
     return bcpnn_fwd(x, proj.w, proj.b, spec.post.H, spec.post.M, spec.gain)
 
 
@@ -142,42 +101,27 @@ def fused_packed_forward(pack: InferPack, spec: ProjSpec,
     kernels in kernels/quant.py with the pack's per-HC scales folded
     into the softmax epilogue.  The patchy index table comes from the
     pack — never re-derived from the mask on the serving path."""
-    b = x.shape[0]
     if pack.w.dtype == jnp.int8:
         if pack.w.ndim == 3:  # compact-resident layout
-            hj, k_units, mj = pack.w.shape
-            kw = _blocks("quant_compact_forward", {}, b=b, k=k_units,
-                         hj=hj, mj=mj)
             return quant_compact_forward(
                 x, pack.w, pack.b, pack.scale, pack.table, spec.pre.M,
-                spec.gain, interpret=_interpret(), **kw)
+                spec.gain, interpret=_interpret())
         if is_patchy(spec) and pack.table is not None:
-            kw = _blocks("quant_patchy_forward", {}, b=b,
-                         k=spec.nact * spec.pre.M, hj=spec.post.H,
-                         mj=spec.post.M)
             return quant_patchy_forward(
                 x, pack.w, pack.b, pack.scale, pack.table, spec.pre.M,
                 spec.post.H, spec.post.M, spec.gain,
-                interpret=_interpret(), **kw)
-        kw = _blocks("quant_fwd", {}, b=b, ni=x.shape[1],
-                     n_hc=spec.post.H, n_mc=spec.post.M)
+                interpret=_interpret())
         return quant_fwd_pallas(x, pack.w, pack.b, pack.scale, spec.post.H,
                                 spec.post.M, spec.gain,
-                                interpret=_interpret(), **kw)
+                                interpret=_interpret())
     if pack.w.ndim == 3:
-        kw = _blocks("compact_forward", {}, b=b,
-                     k=spec.nact * spec.pre.M, hj=spec.post.H,
-                     mj=spec.post.M)
         return compact_forward(x, pack.w, pack.b, pack.table, spec.pre.M,
-                               spec.gain, interpret=_interpret(), **kw)
+                               spec.gain, interpret=_interpret())
     if is_patchy(spec) and pack.table is not None:
-        kw = _blocks("patchy_forward", {}, b=b,
-                     k=spec.nact * spec.pre.M, hj=spec.post.H,
-                     mj=spec.post.M)
         return patchy_forward(
             x, pack.w, pack.b, pack.table, spec.pre.M,
             spec.post.H, spec.post.M, spec.gain,
-            interpret=_interpret(), **kw)
+            interpret=_interpret())
     return bcpnn_fwd(x, pack.w, pack.b, spec.post.H, spec.post.M, spec.gain)
 
 
@@ -224,21 +168,15 @@ def fused_learn(proj: Projection, spec: ProjSpec, x: jax.Array,
     if path == "compact_update":
         # Scatter-free hot path: the kernel reads and writes the resident
         # compact trace/weights — zero O(Ni·Nj) work per step.
-        kw = _blocks("compact_update", {}, b=x.shape[0],
-                     k=spec.nact * spec.pre.M, hj=spec.post.H,
-                     mj=spec.post.M)
         new_pij, w = compact_update(
             tr.pij, log_pi, log_pj, x, y, proj.table, a, spec.pre.M,
-            eps=spec.eps, interpret=_interpret(), **kw)
+            eps=spec.eps, interpret=_interpret())
     elif path == "patchy_update":
-        kw = _blocks("patchy_update", {}, b=x.shape[0],
-                     k=spec.nact * spec.pre.M, hj=spec.post.H,
-                     mj=spec.post.M)
         table = cached_table(proj.mask, spec.nact)
         new_pij, w = patchy_update(
             tr.pij, log_pi, log_pj, x, y, table, a,
             spec.pre.M, spec.post.H, spec.post.M, eps=spec.eps,
-            interpret=_interpret(), **kw)
+            interpret=_interpret())
     else:
         mask = proj.mask if is_patchy(spec) else None
         new_pij, w = bcpnn_update(tr.pij, log_pi, log_pj, x, y, mask, a, n,

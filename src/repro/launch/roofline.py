@@ -35,54 +35,6 @@ _DTYPE_BYTES = {
     "c128": 16, "f8e4m3fn": 1, "f8e5m2": 1,
 }
 
-# Serving-dtype aliases (the repo's ProjSpec.infer_dtype vocabulary and
-# numpy-style names) onto the HLO dtype table above.
-_DTYPE_ALIASES = {
-    "fp32": "f32", "float32": "f32", "int8": "s8", "bfloat16": "bf16",
-    "float16": "f16",
-}
-
-
-def dtype_bytes(dtype: str) -> int:
-    """Bytes per element for an HLO dtype name OR a serving-dtype alias
-    (fp32/bf16/int8...)."""
-    key = _DTYPE_ALIASES.get(dtype, dtype)
-    try:
-        return _DTYPE_BYTES[key]
-    except KeyError:
-        raise ValueError(f"unknown dtype {dtype!r}; known: "
-                         f"{sorted(_DTYPE_BYTES)} + aliases "
-                         f"{sorted(_DTYPE_ALIASES)}") from None
-
-
-def bcpnn_fwd_traffic(batch: int, n_in: int, n_out: int,
-                      weight_dtype: str = "fp32",
-                      act_dtype: str = "fp32",
-                      n_hc: int = 1) -> Dict[str, float]:
-    """First-principles HBM traffic/FLOPs of one inference-only fused
-    BCPNN forward (support matmul + bias + per-HC softmax), parameterized
-    by the serving dtype — the paper's Eq. 2-5 methodology with
-    bytes-per-element as a free variable, so bf16/int8 roofline rows are
-    honest about their bandwidth win instead of assuming f32.
-
-    Model (weights stream once, activations once, output written f32):
-      FLOPs = 2·B·Ni·Nj (support) + ~6·B·Nj (bias + softmax epilogue)
-      Bytes = act·B·Ni (x) + w·(Ni·Nj + Nj) (weights + bias)
-              + 4·n_hc (int8 per-HC scale vector, else 0) + 4·B·Nj (out)
-
-    The EMA/learn traffic is deliberately NOT parameterized: trace state
-    is always fp32 (DESIGN.md §8) — only the inference path changes
-    dtype.
-    """
-    wb = dtype_bytes(weight_dtype)
-    ab = dtype_bytes(act_dtype)
-    flops = 2.0 * batch * n_in * n_out + 6.0 * batch * n_out
-    bytes_ = (ab * batch * n_in + wb * (n_in * n_out + n_out)
-              + (4.0 * n_hc if wb == 1 else 0.0) + 4.0 * batch * n_out)
-    return {"flops": flops, "bytes": bytes_,
-            "intensity": flops / bytes_}
-
-
 _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
 _COLLECTIVES = ("all-gather(", "all-reduce(", "reduce-scatter(",
                 "all-to-all(", "collective-permute(")

@@ -24,12 +24,10 @@ finds (the mesh shrinks to fit when fewer are present).  With no
 accelerator attached, ``main`` gives the host (CPU) platform N virtual
 devices via ``--xla_force_host_platform_device_count``; the flag must act
 before jax initializes, so this module imports jax inside ``run``.
-``--json PATH`` writes the measured numbers for benchmarks/run.py.
 """
 from __future__ import annotations
 
 import argparse
-import json
 import os
 import tempfile
 import time
@@ -53,14 +51,6 @@ def build_parser() -> argparse.ArgumentParser:
                    help="checkpoint cadence in batches for the kill phase")
     p.add_argument("--kill-at-chunk", type=int, default=3,
                    help="which chunk boundary raises the simulated loss")
-    p.add_argument("--warmup", action="store_true",
-                   help="one untimed fit first (compile outside timings)")
-    p.add_argument("--no-single", action="store_true",
-                   help="skip the single-device reference (bench mode)")
-    p.add_argument("--no-kill", action="store_true",
-                   help="skip the kill-resume phase (pure scaling rows)")
-    p.add_argument("--json", type=str, default=None,
-                   help="write measured numbers to this path")
     return p
 
 
@@ -79,8 +69,7 @@ def main(argv=None) -> int:
 
 
 def run(args: argparse.Namespace) -> int:
-    """The three phases on the devices this process already has (what
-    benchmarks/bench_train_dp.py calls in-process)."""
+    """The three phases on the devices this process already has."""
     import jax
     import numpy as np
 
@@ -98,70 +87,39 @@ def run(args: argparse.Namespace) -> int:
     ytr, yte = ds.y_train, ds.y_test
     n_img = len(xtr) * args.epochs * spec.depth
 
-    def fit_once(trainer, **kw):
+    def fit_once(trainer):
         t0 = time.perf_counter()
-        stats = trainer.fit(xtr, ytr, epochs=args.epochs, batch=args.batch,
-                            **kw)
-        return time.perf_counter() - t0, stats
-
-    out = {"train_n": len(xtr),
-           "batch": args.batch, "epochs": args.epochs,
-           "depth": spec.depth}
+        trainer.fit(xtr, ytr, epochs=args.epochs, batch=args.batch)
+        return time.perf_counter() - t0
 
     # ---- phase 1: single-device reference ------------------------------
-    t_single = None
-    ref = None
-    if not args.no_single:
-        tr1 = Trainer(spec, seed=0)
-        if args.warmup:
-            fit_once(tr1)
-            tr1.reset(seed=0)
-        t_single, _ = fit_once(tr1)
-        ref = tr1.state
-        acc1 = tr1.evaluate(xte, yte, batch=args.batch)
-        out["single_s"] = t_single
-        out["single_images_per_s"] = n_img / t_single
-        out["single_acc"] = float(acc1)
-        print(f"[train-dp] single-device: {t_single:.2f}s "
-              f"({n_img / t_single:.0f} img/s), acc {acc1:.3f}")
+    tr1 = Trainer(spec, seed=0)
+    t_single = fit_once(tr1)
+    ref = tr1.state
+    acc1 = tr1.evaluate(xte, yte, batch=args.batch)
+    print(f"[train-dp] single-device: {t_single:.2f}s "
+          f"({n_img / t_single:.0f} img/s), acc {acc1:.3f}")
 
     # ---- phase 2: data-parallel fit on the full mesh -------------------
     mesh = elastic_mesh((args.devices,), ("data",))
     n_dev = mesh.devices.size
-    out["devices"] = n_dev
-    out["platform"] = mesh.devices.flat[0].platform
     print(f"[train-dp] mesh: {describe_failure_domains(mesh)}")
     tr2 = Trainer(spec, seed=0, mesh=mesh)
-    if args.warmup:
-        fit_once(tr2)
-        tr2.reset(seed=0)
-    t_dp, _ = fit_once(tr2)
+    t_dp = fit_once(tr2)
     acc2 = tr2.evaluate(xte, yte, batch=args.batch)
-    out["dp_s"] = t_dp
-    out["dp_images_per_s"] = n_img / t_dp
-    out["dp_acc"] = float(acc2)
-    if t_single is not None:
-        out["scaling_x"] = t_single / t_dp
     print(f"[train-dp] {n_dev}-way DP: {t_dp:.2f}s "
-          f"({n_img / t_dp:.0f} img/s), acc {acc2:.3f}"
-          + (f", scaling {t_single / t_dp:.2f}x" if t_single else ""))
-    if ref is not None:
-        same = all(
-            np.array_equal(np.asarray(a), np.asarray(b))
-            for a, b in zip(jax.tree_util.tree_leaves(ref),
-                            jax.tree_util.tree_leaves(tr2.state)))
-        print(f"[train-dp] DP state bit-identical to single-device: {same}")
-        if args.smoke:
-            assert same, "DP fit diverged from the single-device fit"
-            assert abs(acc1 - acc2) == 0.0
+          f"({n_img / t_dp:.0f} img/s), acc {acc2:.3f}, "
+          f"scaling {t_single / t_dp:.2f}x")
+    same = all(
+        np.array_equal(np.asarray(a), np.asarray(b))
+        for a, b in zip(jax.tree_util.tree_leaves(ref),
+                        jax.tree_util.tree_leaves(tr2.state)))
+    print(f"[train-dp] DP state bit-identical to single-device: {same}")
+    if args.smoke:
+        assert same, "DP fit diverged from the single-device fit"
+        assert abs(acc1 - acc2) == 0.0
 
     # ---- phase 3: kill-resume via elastic_mesh -------------------------
-    if args.no_kill:
-        if args.json:
-            with open(args.json, "w") as f:
-                json.dump(out, f, indent=2)
-        print("[train-dp] smoke OK" if args.smoke else "[train-dp] done")
-        return 0
     with tempfile.TemporaryDirectory() as ckpt_dir:
         chunks = {"n": 0}
 
@@ -198,14 +156,10 @@ def run(args: argparse.Namespace) -> int:
         t_resume = time.perf_counter() - t_rec0
         acc_r = tr_r.evaluate(xte, yte, batch=args.batch)
         overhead = t_killed + t_resume - t_dp
-        out["kill_resume_s"] = t_killed + t_resume
-        out["recovery_overhead_s"] = overhead
-        out["resumed_acc"] = float(acc_r)
         same_r = all(
             np.array_equal(np.asarray(a), np.asarray(b))
             for a, b in zip(jax.tree_util.tree_leaves(tr2.state),
                             jax.tree_util.tree_leaves(tr_r.state)))
-        out["resumed_bit_identical"] = bool(same_r)
         print(f"[train-dp] kill-resume on {len(survivors)} device(s): "
               f"{t_killed + t_resume:.2f}s total "
               f"({overhead:+.2f}s vs uninterrupted), acc {acc_r:.3f}, "
@@ -219,10 +173,6 @@ def run(args: argparse.Namespace) -> int:
                   f"{len(tr_r.timer.events)} (last: "
                   f"{tr_r.timer.events[-1]})")
 
-    if args.json:
-        with open(args.json, "w") as f:
-            json.dump(out, f, indent=2)
-        print(f"[train-dp] wrote {args.json}")
     print("[train-dp] smoke OK" if args.smoke else "[train-dp] done")
     return 0
 

@@ -311,22 +311,33 @@ def test_serve_infer_dtype_validation():
         BCPNNService(state, spec, infer_dtype="fp16")
 
 
-# -------------------------------------------------- roofline traffic ----
+# --------------------------------------------- end-to-end accuracy gate --
 
-def test_roofline_dtype_traffic_ordering():
-    """Modeled arithmetic intensity must rise with narrower weights, and
-    the int8 model must account for its f32 scale vector."""
-    from repro.launch.roofline import bcpnn_fwd_traffic, dtype_bytes
+MAX_QUANT_ACC_DELTA_PP = 0.5  # low-precision eval may lose at most this
 
-    assert dtype_bytes("fp32") == 4 and dtype_bytes("bf16") == 2
-    assert dtype_bytes("int8") == 1 and dtype_bytes("f32") == 4
-    with pytest.raises(ValueError, match="unknown dtype"):
-        dtype_bytes("q4")
-    args = dict(batch=64, n_in=1568, n_out=4096, n_hc=32)
-    t32 = bcpnn_fwd_traffic(**args, weight_dtype="fp32")
-    t16 = bcpnn_fwd_traffic(**args, weight_dtype="bf16")
-    t8 = bcpnn_fwd_traffic(**args, weight_dtype="int8")
-    assert t32["intensity"] < t16["intensity"] < t8["intensity"]
-    assert t32["flops"] == t16["flops"] == t8["flops"]
-    t8_nh = bcpnn_fwd_traffic(**{**args, "n_hc": 1}, weight_dtype="int8")
-    assert t8["bytes"] - t8_nh["bytes"] == pytest.approx(4 * 31)
+
+def test_low_precision_eval_accuracy_within_budget():
+    """A small compact-patchy network trained on the synthetic task, then
+    the SAME fp32 state evaluated under each serving dtype (``infer``
+    reroutes low-precision specs through the packed path, so this measures
+    what the engine serves): bf16 and int8 each lose at most 0.5 pp of
+    eval accuracy against fp32."""
+    from repro.configs.bcpnn_models import deep_synth_spec
+    from repro.core import Trainer, evaluate_padded
+    from repro.data.synthetic import encode_images, make_synthetic
+
+    ds = make_synthetic(768, 256, 8, 4, seed=3, max_shift=1)
+    xt, xe = encode_images(ds.x_train), encode_images(ds.x_test)
+    spec = deep_synth_spec(side=8, depth=1, n_classes=4, hidden_hc=8,
+                           hidden_mc=16, nact=[32], patchy_traces=True,
+                           compact=True, struct_every=25, backend="pallas")
+    tr = Trainer(spec, seed=0)
+    tr.fit(xt, ds.y_train, epochs=6, batch=64)
+    acc32 = evaluate_padded(tr.state, spec, xe, ds.y_test, 64)
+    for dt in ("bf16", "int8"):
+        acc = evaluate_padded(tr.state, spec.with_infer_dtype(dt),
+                              xe, ds.y_test, 64)
+        delta_pp = (acc32 - acc) * 100
+        assert delta_pp <= MAX_QUANT_ACC_DELTA_PP, (
+            f"{dt} inference lost {delta_pp:.2f} pp against fp32 "
+            f"({acc32 * 100:.2f}% -> {acc * 100:.2f}%)")
